@@ -103,12 +103,19 @@ print("PROBE_JSON:" + json.dumps(out))
 """
 
 
+# Tag for rows derived from the probe below: element counts taken on
+# forced host CPU devices, never a time.
+PROBE_DEVICES = "cpu-host-devices"
+
+
 def shardmap_payload_probe(scale: int, p: int, algos=("pagerank", "bfs"),
                            edge_factor=16, seed=7, batch_size=64,
                            timeout=1800) -> dict:
-    """Run SHARD_MAP algorithms on ``p`` forced host devices in a child
-    process (the main process keeps seeing one device) and return
-    ``{algo: counters}``.  The engine is built with defaults, so the
+    """Run SHARD_MAP algorithms on ``p`` forced host CPU devices in a
+    child process (the main process keeps seeing one device) and return
+    ``{algo: counters}``.  The child is pinned to ``JAX_PLATFORMS=cpu`` so
+    it never claims an accelerator the parent holds; it measures payload
+    element counts, not time.  The engine is built with defaults, so the
     physical sparse exchange arbitrates per iteration (DESIGN.md §12) and
     the counters carry the dense-vs-compacted payload-element pair."""
     import json
@@ -116,7 +123,8 @@ def shardmap_payload_probe(scale: int, p: int, algos=("pagerank", "bfs"),
     cfg = dict(scale=scale, p=p, algos=list(algos), edge_factor=edge_factor,
                seed=seed, batch_size=batch_size)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "-c", _SHARDMAP_PROBE_CODE, json.dumps(cfg)],
         capture_output=True, text=True, env=env, cwd=repo, timeout=timeout)

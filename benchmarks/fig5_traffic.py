@@ -30,7 +30,7 @@ import numpy as np
 
 from benchmarks.engines_common import (
     bench_graph, bench_record, build_engine, csv_row, merge_bench_json,
-    shardmap_payload_probe, timed, write_bench_json,
+    PROBE_DEVICES, shardmap_payload_probe, timed, write_bench_json,
 )
 from repro.core import (
     ChunkStore, Engine, EngineConfig, accumulate_counters, storage_summary,
@@ -285,7 +285,8 @@ def _shardmap_section(scale=11) -> list[str]:
                  "iters"),
                 ("dense_iters", c["exchange_dense_iters"], "iters")):
             records.append(bench_record(
-                "fig5_shardmap", f"{algo}/p{p}", metric, val, units))
+                "fig5_shardmap", f"{algo}/p{p}/{PROBE_DEVICES}", metric, val,
+                units))
     path = merge_bench_json("BENCH_shardmap.json", records)
     rows.append(csv_row("f5/shardmap/bench_json", 0.0, f"path={path}"))
     return rows

@@ -13,6 +13,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def main() -> None:
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (
         ablations, fig5_traffic, fig6_batchsize, kernels_micro,
         table45_engines, table6_batching, table7_scaling,

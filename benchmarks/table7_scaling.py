@@ -34,7 +34,7 @@ import numpy as np
 
 from benchmarks.engines_common import (
     bench_graph, bench_record, csv_row, merge_bench_json,
-    shardmap_payload_probe, timed, write_bench_json,
+    PROBE_DEVICES, shardmap_payload_probe, timed, write_bench_json,
 )
 from repro.core import (
     ChunkStore, Engine, EngineConfig, build_dist_graph, build_formats,
@@ -181,7 +181,7 @@ def main(scale=10) -> list[str]:
                             ("compacted_iters",
                              c["exchange_compacted_iters"])):
             sm_records.append(bench_record(
-                "table7_shardmap", f"bfs/p{p}", metric, val,
+                "table7_shardmap", f"bfs/p{p}/{PROBE_DEVICES}", metric, val,
                 "elems" if "elems" in metric else "iters"))
     sm_path = merge_bench_json("BENCH_shardmap.json", sm_records)
     rows.append(csv_row("t7/shardmap/bench_json", 0.0, f"path={sm_path}"))

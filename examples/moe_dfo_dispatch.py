@@ -20,9 +20,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.core.sparse_collectives import (  # noqa: E402
     dense_combine, dense_dispatch, topk_routing,
 )
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     mesh = jax.make_mesh((2, 4), ("data", "model"))
     t, d, e, k = 64, 32, 8, 2
     cap = int(1.25 * t * k / e)

@@ -15,9 +15,11 @@ from repro.ckpt import BlockStore  # noqa: E402
 from repro.core import Engine, build_dist_graph, build_formats, make_spec  # noqa: E402
 from repro.core import algorithms as alg  # noqa: E402
 from repro.data.graphs import rmat_graph  # noqa: E402
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     print("== build graph (R-MAT scale 10, edge factor 16) ==")
     g = rmat_graph(10, 16, seed=42, weighted=True)
     print(f"|V|={g.num_vertices}  |E|={g.num_edges}")

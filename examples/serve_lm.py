@@ -15,9 +15,11 @@ from repro.configs import get_reduced  # noqa: E402
 from repro.models.model import make_model  # noqa: E402
 from repro.serve.engine import ServeSession  # noqa: E402
 from repro.sharding.rules import make_rules  # noqa: E402
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     rules = make_rules(None)
     rng = np.random.default_rng(0)
     for arch in ("yi-6b", "rwkv6-1.6b", "zamba2-1.2b"):

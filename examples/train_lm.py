@@ -22,9 +22,11 @@ from repro.models.model import make_model  # noqa: E402
 from repro.sharding.rules import make_rules  # noqa: E402
 from repro.train.loop import init_train_state, make_train_step  # noqa: E402
 from repro.train.optimizer import OptConfig  # noqa: E402
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="yi-6b")

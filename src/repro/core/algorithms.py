@@ -322,8 +322,7 @@ def ref_pagerank(n, src, dst, num_iters=5, damping=0.85):
     outdeg = np.maximum(np.bincount(src, minlength=n), 1)
     for _ in range(num_iters):
         contrib = rank[src] / outdeg[src]
-        acc = np.zeros(n, np.float64)
-        np.add.at(acc, dst, contrib)
+        acc = np.bincount(dst, weights=contrib, minlength=n)
         rank = (1 - damping) / n + damping * acc
     return rank
 
@@ -342,24 +341,22 @@ def ref_ppr(n, src, dst, source, num_iters=5, damping=0.85):
 
 
 def ref_bfs(n, src, dst, source):
+    """Level-synchronous BFS over a CSR of the edge list: each round
+    expands the whole frontier at once."""
     inf = np.float32(np.finfo(np.float32).max)
     level = np.full(n, inf, np.float32)
     level[source] = 0
+    d_sorted = dst[np.argsort(src)]
+    starts = np.concatenate([[0], np.cumsum(np.bincount(src, minlength=n))])
     frontier = np.array([source])
     d = 0
-    # CSR for speed
-    order = np.argsort(src, kind="stable")
-    s_sorted, d_sorted = src[order], dst[order]
-    starts = np.searchsorted(s_sorted, np.arange(n + 1))
     while frontier.size:
         d += 1
-        nxt = []
-        for v in frontier:
-            nbrs = d_sorted[starts[v]:starts[v + 1]]
-            new = nbrs[level[nbrs] > d]
-            level[new] = d
-            nxt.append(np.unique(new))
-        frontier = np.unique(np.concatenate(nxt)) if nxt else np.array([], np.int64)
+        lo, deg = starts[frontier], starts[frontier + 1] - starts[frontier]
+        first = np.cumsum(deg) - deg
+        nbrs = d_sorted[np.repeat(lo - first, deg) + np.arange(deg.sum())]
+        frontier = np.unique(nbrs[level[nbrs] > d])
+        level[frontier] = d
     return level
 
 

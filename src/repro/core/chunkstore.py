@@ -680,17 +680,25 @@ class ChunkStore:
         return damage
 
 
+def _bucket(n: int) -> int:
+    """Power-of-two padded width, at least 512 (the kernels' block): each
+    chunk pads to at most twice its own size, and each kernel compiles
+    for O(log) widths instead of one per chunk."""
+    return 1 << max(9, (max(int(n), 1) - 1).bit_length())
+
+
 class DeviceChunkDecoder:
     """Fused on-device chunk decode for one compressed store (DESIGN.md §10).
 
-    Holds the static padded shapes — per-store maxima over chunk nnz, edge
-    counts, and varint section bytes — that key the jit-compiled Pallas
-    pipeline of :mod:`repro.kernels.varint`, so every chunk of the store
-    decodes through a handful of fixed-shape compiled programs.  Per call,
-    the raw section bytes are staged into zero-padded buffers, the varint /
-    delta / run-expand kernels run on device, and only the exact-length
+    Each chunk's raw section bytes are staged into zero-padded buffers of
+    power-of-two widths (:func:`_bucket` of its own edge, run and byte
+    counts), the varint / delta / run-expand kernels of
+    :mod:`repro.kernels.varint` run on device, and only the exact-length
     ``(src, dst, data)`` triple is synced back — bit-identical to
-    :meth:`ChunkStore.decode_chunk`.
+    :meth:`ChunkStore.decode_chunk`.  Padding to a chunk's own bucket
+    rather than the store's largest chunk keeps the device work
+    proportional to the bytes read.  ``max_widths`` records the largest
+    padded widths this store can produce.
     """
 
     def __init__(self, store: ChunkStore):
@@ -702,18 +710,15 @@ class DeviceChunkDecoder:
         from repro.kernels import varint as vk
         self._vk = vk
         self.store = store
-        max_nnz = max_edges = pair_nb = dstv_nb = 1
+        widths = dict(edges=1, runs=1, pair_bytes=1, residue_bytes=1)
         for q in store.partitions:
             lay = store._layout_of(q)
             if lay.nnz.size:
-                max_nnz = max(max_nnz, int(lay.nnz.max()))
-                max_edges = max(max_edges, int(lay.edges.max()))
-                pair_nb = max(pair_nb, int(lay.pair_nb.max()))
-                dstv_nb = max(dstv_nb, int(lay.dstv_nb.max()))
-        self._max_nnz = max_nnz
-        self._epad = max_edges
-        self._pair_nb_pad = pair_nb
-        self._dstv_nb_pad = dstv_nb
+                for k, arr in (("edges", lay.edges), ("runs", lay.nnz),
+                               ("pair_bytes", lay.pair_nb),
+                               ("residue_bytes", lay.dstv_nb)):
+                    widths[k] = max(widths[k], int(arr.max()))
+        self.max_widths = {k: _bucket(v) for k, v in widths.items()}
         self._vpad = int(store.part_sizes.max()) + 1
 
     def decode(self, q: int, p: int, k: int, rep: int,
@@ -726,31 +731,30 @@ class DeviceChunkDecoder:
         v_src = int(store.part_sizes[p])
         vnb = int(lay.dstv_nb[p, k])
         base = k * store.batch_size
-        epad = self._epad
+        epad = _bucket(n_e)
         if rep == REP_CSR:
             idx = np.zeros(self._vpad, np.int32)
             idx[:v_src + 1] = np.frombuffer(index, "<i4")
             src_d, smask = vk.expand_csr_index(idx, v_src, n_e,
                                                out_len=epad)
         elif rep == REP_DCSR_DELTA:
-            pb = np.zeros(self._pair_nb_pad, np.uint8)
+            pb = np.zeros(_bucket(len(index)), np.uint8)
             pb[:len(index)] = np.frombuffer(index, np.uint8)
-            pv = vk.varint_decode(pb, len(index),
-                                  count=2 * self._max_nnz)
+            pv = vk.varint_decode(pb, len(index), count=2 * _bucket(nnz))
             srcs, starts = vk.pair_delta_restore(pv)
             src_d, smask = vk.expand_dcsr_index(srcs, starts, nnz, n_e,
                                                 out_len=epad)
         elif rep == REP_DCSR:
             pairs = np.frombuffer(index, PAIR_DT)
-            srcs = np.zeros(self._max_nnz, np.int32)
-            starts = np.zeros(self._max_nnz, np.int32)
+            srcs = np.zeros(_bucket(nnz), np.int32)
+            starts = np.zeros(_bucket(nnz), np.int32)
             srcs[:nnz] = pairs["src"]
             starts[:nnz] = pairs["idx"]
             src_d, smask = vk.expand_dcsr_index(srcs, starts, nnz, n_e,
                                                 out_len=epad)
         else:
             raise ValueError(f"unknown chunk representation {rep!r}")
-        db = np.zeros(self._dstv_nb_pad, np.uint8)
+        db = np.zeros(_bucket(vnb), np.uint8)
         db[:vnb] = np.frombuffer(payload[:vnb], np.uint8)
         res = vk.varint_decode(db, vnb, count=epad)
         dst_d = vk.dst_delta_restore(res, smask, base, n_e)

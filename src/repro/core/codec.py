@@ -173,19 +173,26 @@ def mask_gap_bytes(mask, xp=np):
     jitted LOCAL / SHARD_MAP network counters can price the same encoding
     the dist_ooc wire physically emits.  Host (numpy) callers sum in
     float64 — exact against the integer byte counts the encoder measures;
-    the jit path keeps the counters' float32."""
+    the jit path keeps the counters' float32.  The host path visits only
+    the set positions, so its cost follows the message count, not V."""
     v = mask.shape[-1]
+    if xp is np:
+        m = np.asarray(mask, bool)
+        rows = m.reshape(int(np.prod(m.shape[:-1])), v)
+        r, c = np.nonzero(rows)                  # row-major: sorted gaps
+        prev = np.empty_like(c)
+        prev[:1] = -1
+        prev[1:] = c[:-1]
+        prev[np.flatnonzero(np.diff(r, prepend=-1))] = -1   # row starts
+        nb = varint_sizes(c - prev).astype(np.float64)
+        return np.bincount(r, weights=nb, minlength=rows.shape[0]
+                           ).reshape(m.shape[:-1])
+    import jax
     idx = xp.arange(v, dtype=xp.int32)
     filled = xp.where(mask, idx, xp.int32(-1))
-    if xp is np:
-        run = np.maximum.accumulate(filled, axis=-1)
-    else:
-        import jax
-        run = jax.lax.cummax(filled, axis=mask.ndim - 1)
+    run = jax.lax.cummax(filled, axis=mask.ndim - 1)
     prev = xp.concatenate(
         [xp.full(mask.shape[:-1] + (1,), -1, xp.int32), run[..., :-1]],
         axis=-1)
-    gap = idx - prev
-    nb = varint_sizes(gap, xp=xp)
-    acc = xp.float64 if xp is np else xp.float32
-    return xp.sum(xp.where(mask, nb, 0).astype(acc), axis=-1)
+    nb = varint_sizes(idx - prev, xp=xp)
+    return xp.sum(xp.where(mask, nb, 0).astype(xp.float32), axis=-1)

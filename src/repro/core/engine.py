@@ -151,9 +151,10 @@ class EngineConfig:
     block-CSR tile kernel with zero-skipping of chunks that received no
     messages — DESIGN.md §4).  Non-affine slot functions fall back to
     segment with a warning.  Note: the ooc / dist_ooc executors evaluate
-    segment-backend ``slot_fn`` on host **numpy** arrays (the streamed
-    per-batch calls must not route through jax's eager dispatch, which
-    serializes parallel workers — DESIGN.md §8); write slots as plain
+    ``slot_fn`` on host **numpy** arrays per streamed batch, for either
+    backend (those calls must not route through jax's eager dispatch,
+    which serializes parallel workers — DESIGN.md §8 — and compiles per
+    batch length); write slots as plain
     array arithmetic, valid for numpy and jnp operands alike, as all four
     paper algorithms do."""
 
@@ -887,8 +888,9 @@ class Engine:
                     None if active is None else P(axis), P(axis), P(axis))
         out_specs = (jax.tree_util.tree_map(lambda _: P(axis), state),
                      P(), {k: P() for k in COUNTER_KEYS})
-        fn = jax.jit(_executor.shard_map_compat(
-            inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+        fn = jax.jit(jax.shard_map(
+            inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False))
         return fn(state, active, self._garrs["vertex_valid"],
                   self._garrs["global_id"])
 
@@ -1349,8 +1351,9 @@ class Engine:
                     None if active is None else P(axis), P(axis), P(axis))
         out_specs = ({k: P(axis) for k in state}, P(),
                      {k: P() for k in COUNTER_KEYS})
-        fn = jax.jit(_executor.shard_map_compat(
-            inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+        fn = jax.jit(jax.shard_map(
+            inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False))
         return fn(state, active, self._garrs["vertex_valid"],
                   self._garrs["global_id"])
 

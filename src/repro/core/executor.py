@@ -74,19 +74,6 @@ from repro.kernels.csr_spmv import (
 from repro.utils import ceil_div, token_ctx
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map moved around across jax versions; Pallas calls inside
-    the mapped function additionally need replication checks disabled."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 # ---------------------------------------------------------------------------
 # Slot lowering for the block-CSR backend (DESIGN.md §2)
 # ---------------------------------------------------------------------------
@@ -428,8 +415,8 @@ def make_sharded_probe(engine, has_active, garrs_keys, nq=1):
 
     in_specs = (P(axis) if has_active else None,
                 {k: P(axis) for k in garrs_keys})
-    return jax.jit(shard_map_compat(pstep, mesh=mesh, in_specs=in_specs,
-                                    out_specs=P()))
+    return jax.jit(jax.shard_map(pstep, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False))
 
 
 def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
@@ -565,9 +552,12 @@ def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
                         None if vals is None else {k: P(axis) for k in vals})
             out_specs = ({k: P(axis) for k in state}, P(axis), P(),
                          {k: P() for k in counter_keys})
-            fn = jax.jit(shard_map_compat(
+            # check_vma=False: the Pallas combine kernel's out_shape
+            # carries no varying-mesh-axis annotation
+            fn = jax.jit(jax.shard_map(
                 functools.partial(step, wire_capacity=wire_capacity),
-                mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+                mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                check_vma=False))
             jitted[skey] = fn
         return fn(state, active, garrs, bt, vals)
     return run_sharded
@@ -647,12 +637,14 @@ def _stream_value_tiles(work, cells, n_slots, slot_fn, monoid, mode, tile):
     """Scatter the per-edge affine coefficients of one streamed dst-batch
     into value tiles: (tiles_cnt, tiles_v, tiles_b).  The coefficients are
     probed on the streamed edge data (affinity was certified by the
-    engine's slot probe); like the layout, they are query-independent."""
+    engine's slot probe); like the layout, they are query-independent.
+    The slot runs on host numpy, as in the segment combine: every batch
+    has its own edge count, and a device call would compile per count."""
     t = tile
     identity = float(monoid.identity)
-    d = jnp.asarray(work.data)
-    b_e = np.asarray(slot_fn(jnp.zeros_like(d), d), np.float32)
-    a_e = np.asarray(slot_fn(jnp.ones_like(d), d), np.float32) - b_e
+    d = np.asarray(work.data, np.float32)
+    b_e = np.asarray(slot_fn(np.zeros_like(d), d), np.float32)
+    a_e = np.asarray(slot_fn(np.ones_like(d), d), np.float32) - b_e
     tiles_cnt = np.zeros((n_slots, t, t), np.float32)
     np.add.at(tiles_cnt, cells, 1.0)
     tiles_v = tiles_b = None
@@ -726,12 +718,15 @@ def _dispatch_schedule_one_dest(source, q, recv_mask_q, part_sizes, gamma,
         source.dcsr_raw_bytes[q].astype(np.float32),
         part_sizes, gamma, msgs_from, compression, xp=np)
     rep = np.where(uc, REP_CSR, np.where(ud, REP_DCSR_DELTA, REP_DCSR))
+    # float64 sums: float32 stops being exact past 2**24 bytes, and the
+    # measured side counts every byte
+    red = lambda x: float(x[chunk_active].sum(dtype=np.float64))  # noqa: E731
     cd = {
         "msgs_dispatched": float(present.sum()),
         "chunks_read": float(chunk_active.sum()),
-        "seek_cost": float(seek[chunk_active].sum()),
-        "edge_read_bytes": float(per_chunk[chunk_active].sum()),
-        "edge_read_bytes_raw": float(per_raw[chunk_active].sum()),
+        "seek_cost": red(seek),
+        "edge_read_bytes": red(per_chunk),
+        "edge_read_bytes_raw": red(per_raw),
         "chunks_read_csr": float((chunk_active & uc).sum()),
         "chunks_read_dcsr_delta": float((chunk_active & ud).sum()),
         "chunks_read_dcsr": float((chunk_active & ~uc & ~ud).sum()),

@@ -52,7 +52,7 @@ from repro.core.executor import (
     DestHeader, _apply_and_account, _batch_any, _block_dest_vectors,
     _combine_stream_batch, _max_tiles_per_batch_row, _stream_tile_layout,
     _stream_value_tiles, _zero_counters, make_sharded_probe,
-    run_worker_pool, shard_map_compat,
+    run_worker_pool,
 )
 from repro.kernels.csr_spmv import block_csr_combine_mq, default_interpret
 from repro.utils import ceil_div, token_ctx
@@ -97,12 +97,15 @@ def _dispatch_schedule_one_dest_mq(source, q, union_mask_q, part_sizes,
         source.dcsr_raw_bytes[q].astype(np.float32),
         part_sizes, gamma, msgs_from, compression, xp=np)
     rep = np.where(uc, REP_CSR, np.where(ud, REP_DCSR_DELTA, REP_DCSR))
+    # float64 sums: float32 stops being exact past 2**24 bytes, and the
+    # measured side counts every byte
+    red = lambda x: float(x[chunk_active].sum(dtype=np.float64))  # noqa: E731
     cd = {
         "msgs_dispatched": float(present.sum()),
         "chunks_read": float(chunk_active.sum()),
-        "seek_cost": float(seek[chunk_active].sum()),
-        "edge_read_bytes": float(per_chunk[chunk_active].sum()),
-        "edge_read_bytes_raw": float(per_raw[chunk_active].sum()),
+        "seek_cost": red(seek),
+        "edge_read_bytes": red(per_chunk),
+        "edge_read_bytes_raw": red(per_raw),
         "chunks_read_csr": float((chunk_active & uc).sum()),
         "chunks_read_dcsr_delta": float((chunk_active & ud).sum()),
         "chunks_read_dcsr": float((chunk_active & ~uc & ~ud).sum()),
@@ -474,9 +477,10 @@ def make_sharded_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, nq,
                         {k: P(axis) for k in garrs})
             out_specs = ({k: P(axis) for k in state}, P(axis), P(),
                          {k: P() for k in engine.counter_keys})
-            fn = jax.jit(shard_map_compat(
+            fn = jax.jit(jax.shard_map(
                 functools.partial(step, wire_capacity=wire_capacity),
-                mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+                mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                check_vma=False))
             jitted[skey] = fn
         return fn(state, active, garrs)
     return run_sharded

@@ -193,8 +193,13 @@ def build_dist_graph(graph: GraphData, spec: TwoLevelSpec) -> DistGraph:
     # Sort edges by (dst_partition, src_partition, dst_batch, src, dst):
     # column-oriented chunk order, CSR-by-source inside each chunk (so DCSR
     # (src, idx) seek ranges are contiguous; segment-reduce by dst does not
-    # need dst-sorted order).
-    order = np.lexsort((dst, src, dst_batch, src_part, dst_part))
+    # need dst-sorted order).  Two stable argsorts, by dst and then by the
+    # other four keys packed into one int64, give lexsort's order in about
+    # half its time.
+    key = (((dst_part * p_cnt + src_part) * b_cnt + dst_batch)
+           * graph.num_vertices + src)
+    order = np.argsort(dst, kind="stable")
+    order = order[np.argsort(key[order], kind="stable")]
     src_part_s = src_part[order]
     dst_part_s = dst_part[order]
     src_local_s = src_local[order]

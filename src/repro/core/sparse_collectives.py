@@ -182,16 +182,6 @@ def filtered_all_to_all(payload: jnp.ndarray, send_mask: jnp.ndarray,
     return recv, rmask
 
 
-def _axis_size(axis: str) -> int:
-    """Mesh-axis length inside shard_map.  ``jax.lax.axis_size`` does not
-    exist on every jax this repo supports (absent in 0.4.x); ``psum(1)``
-    is the portable spelling and folds to a constant at trace time."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis)
-    return jax.lax.psum(1, axis)
-
-
 def capacity_bucket(count: int, floor: int = 8) -> int:
     """Round a live-count bound up to a power-of-two capacity bucket.
 
@@ -229,7 +219,7 @@ def compacted_all_to_all(payload: jnp.ndarray, dest: jnp.ndarray,
     were dropped and the caller must fall back to a dense exchange
     (:func:`filtered_all_to_all`) rather than use the truncated result.
     """
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     v, d = payload.shape
     dest0 = jnp.maximum(dest, 0)
     # stable position of each entry within its destination's send buffer

@@ -23,14 +23,18 @@ Two kernels:
   grid row pointer sweeps live tiles only, matching the paper's "only active
   chunks are read" I/O claim on the compute side.
 
-``interpret`` defaults to auto-detection: the Pallas interpreter off-TPU
-(this container), Mosaic lowering on real TPU.  ``REPRO_PALLAS_COMPILE=1``
-forces compilation everywhere.
+TPU layout: Mosaic tiles the last two dims of every block by (8, 128)
+unless a block dim spans the whole array dim, so vectors travel as
+``[blocks, 1, T]`` arrays with ``(1, 1, T)`` blocks (the wrappers reshape
+the flat ``[n * T]`` vectors callers pass), and each tile matvec runs at
+``HIGHEST`` precision so the MXU keeps full f32.
+
+``interpret`` defaults to the backend: the Pallas interpreter off-TPU,
+Mosaic lowering on a TPU.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -38,17 +42,16 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def default_interpret() -> bool:
-    """Interpret off-TPU, compile on TPU (REPRO_PALLAS_COMPILE=1 forces
-    compilation for e.g. CPU-lowering smoke tests)."""
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
+    """Interpret off-TPU, compile on TPU: the backend alone decides."""
     return jax.default_backend() != "tpu"
+
+
+def _dot(tile, x):
+    """[T, T] tile times a [T] vector, full f32 on the MXU."""
+    return jnp.dot(tile, x, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -58,9 +61,9 @@ def default_interpret() -> bool:
 def _kernel(row_ptr_ref, col_ref, tiles_ref, x_ref, out_ref):
     """One (row block r, tile slot j) grid step.
 
-    tiles_ref block: [T, T] — tile j of row r (zero tile if padding)
-    x_ref block:     [T]    — source block selected by col[row_ptr[r]+j]
-    out_ref block:   [T]    — dst accumulator (revisited across j)
+    tiles_ref block: [T, T]    — tile j of row r (zero tile if padding)
+    x_ref block:     [1, 1, T] — source block selected by col[row_ptr[r]+j]
+    out_ref block:   [1, 1, T] — dst accumulator (revisited across j)
     """
     j = pl.program_id(1)
 
@@ -68,9 +71,7 @@ def _kernel(row_ptr_ref, col_ref, tiles_ref, x_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tile = tiles_ref[...]
-    x = x_ref[...]
-    out_ref[...] += jnp.dot(tile, x, preferred_element_type=jnp.float32)
+    out_ref[0, 0] += _dot(tiles_ref[...], x_ref[0, 0])
 
 
 @functools.partial(jax.jit,
@@ -95,23 +96,26 @@ def block_csr_spmv(tiles: jnp.ndarray, tile_col: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, t, t),
                          lambda r, j, row_ptr, col: (row_ptr[r] + j, 0, 0)),
-            pl.BlockSpec((t,),
-                         lambda r, j, row_ptr, col: (col[row_ptr[r] + j],)),
+            pl.BlockSpec((1, 1, t),
+                         lambda r, j, row_ptr, col:
+                         (col[row_ptr[r] + j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((t,), lambda r, j, row_ptr, col: (r,)),
+        out_specs=pl.BlockSpec((1, 1, t),
+                               lambda r, j, row_ptr, col: (r, 0, 0)),
     )
 
     def kernel(row_ptr_ref, col_ref, tiles_ref, x_ref, out_ref):
         _kernel(row_ptr_ref, col_ref, tiles_ref[0], x_ref, out_ref)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_rows * t,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_rows, 1, t), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(row_ptr, tile_col, tiles, x)
+    )(row_ptr, tile_col, tiles, x.reshape(-1, 1, t))
+    return out.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -152,10 +156,8 @@ def _make_combine_kernel(mode: str, identity: float):
 
             @pl.when(j < cnt_ref[r])
             def _():
-                val_ref[...] += jnp.dot(tv_ref[0], xv_ref[...],
-                                        preferred_element_type=jnp.float32)
-                hc_ref[...] += jnp.dot(tc_ref[0], xc_ref[...],
-                                       preferred_element_type=jnp.float32)
+                val_ref[0, 0] += _dot(tv_ref[0], xv_ref[0, 0])
+                hc_ref[0, 0] += _dot(tc_ref[0], xc_ref[0, 0])
         return kernel
 
     if mode == "add_b":
@@ -169,13 +171,9 @@ def _make_combine_kernel(mode: str, identity: float):
 
             @pl.when(j < cnt_ref[r])
             def _():
-                val_ref[...] += (
-                    jnp.dot(tv_ref[0], xv_ref[...],
-                            preferred_element_type=jnp.float32)
-                    + jnp.dot(tb_ref[0], xc_ref[...],
-                              preferred_element_type=jnp.float32))
-                hc_ref[...] += jnp.dot(tc_ref[0], xc_ref[...],
-                                       preferred_element_type=jnp.float32)
+                val_ref[0, 0] += (_dot(tv_ref[0], xv_ref[0, 0])
+                                  + _dot(tb_ref[0], xc_ref[0, 0]))
+                hc_ref[0, 0] += _dot(tc_ref[0], xc_ref[0, 0])
         return kernel
 
     reduce = jnp.min if mode == "min" else jnp.max
@@ -190,10 +188,9 @@ def _make_combine_kernel(mode: str, identity: float):
 
         @pl.when(j < cnt_ref[r])
         def _():
-            contrib = tb_ref[0] + xv_ref[...][None, :]        # [T, T]
-            val_ref[...] = comb(val_ref[...], reduce(contrib, axis=1))
-            hc_ref[...] += jnp.dot(tc_ref[0], xc_ref[...],
-                                   preferred_element_type=jnp.float32)
+            contrib = tb_ref[0] + xv_ref[0]                  # [T, T]
+            val_ref[0, 0] = comb(val_ref[0, 0], reduce(contrib, axis=1))
+            hc_ref[0, 0] += _dot(tc_ref[0], xc_ref[0, 0])
     return kernel
 
 
@@ -216,6 +213,8 @@ def block_csr_combine(row_ptr, tile_idx, tile_col, row_cnt,
     tiles_cnt [S, T, T] f32: per-cell valid-edge multiplicities.
     xv [C * T] f32: slot-transformed masked messages (identity where absent).
     xc [C * T] f32: 0/1 message-presence mask.
+    (Vectors are re-laid as [C, 1, T] for the kernel; see the module
+    docstring.)
 
     Returns (val [R*T] f32 — monoid aggregate, identity where nothing
     arrived; hascnt [R*T] f32 — number of live edges that delivered)."""
@@ -232,9 +231,11 @@ def block_csr_combine(row_ptr, tile_idx, tile_col, row_cnt,
         (1, t, t), lambda r, j, rp, idx, col, cnt:
         (idx[slot(r, j, rp, idx, col, cnt)], 0, 0))
     vec_spec = pl.BlockSpec(
-        (t,), lambda r, j, rp, idx, col, cnt:
-        (col[slot(r, j, rp, idx, col, cnt)],))
-    out_spec = pl.BlockSpec((t,), lambda r, j, rp, idx, col, cnt: (r,))
+        (1, 1, t), lambda r, j, rp, idx, col, cnt:
+        (col[slot(r, j, rp, idx, col, cnt)], 0, 0))
+    out_spec = pl.BlockSpec((1, 1, t),
+                            lambda r, j, rp, idx, col, cnt: (r, 0, 0))
+    xv, xc = xv.reshape(-1, 1, t), xc.reshape(-1, 1, t)
 
     if mode == "add":
         tensors = (tiles_v, tiles_cnt, xv, xc)
@@ -255,15 +256,16 @@ def block_csr_combine(row_ptr, tile_idx, tile_col, row_cnt,
         out_specs=[out_spec, out_spec],
     )
 
-    return pl.pallas_call(
+    val, hc = pl.pallas_call(
         _make_combine_kernel(mode, identity),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_rows * t,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_rows * t,), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((n_rows, 1, t), jnp.float32),
+                   jax.ShapeDtypeStruct((n_rows, 1, t), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(row_ptr, tile_idx, tile_col, row_cnt, *tensors)
+    return val.reshape(-1), hc.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -297,12 +299,8 @@ def _make_combine_kernel_mq(mode: str, identity: float, num_queries: int):
             @pl.when(j < cnt_ref[r])
             def _():
                 for c in range(nq):
-                    val_ref[:, c] += jnp.dot(
-                        tv_ref[0], xv_ref[:, c],
-                        preferred_element_type=jnp.float32)
-                    hc_ref[:, c] += jnp.dot(
-                        tc_ref[0], xc_ref[:, c],
-                        preferred_element_type=jnp.float32)
+                    val_ref[:, c] += _dot(tv_ref[0], xv_ref[:, c])
+                    hc_ref[:, c] += _dot(tc_ref[0], xc_ref[:, c])
         return kernel
 
     if mode == "add_b":
@@ -317,14 +315,9 @@ def _make_combine_kernel_mq(mode: str, identity: float, num_queries: int):
             @pl.when(j < cnt_ref[r])
             def _():
                 for c in range(nq):
-                    val_ref[:, c] += (
-                        jnp.dot(tv_ref[0], xv_ref[:, c],
-                                preferred_element_type=jnp.float32)
-                        + jnp.dot(tb_ref[0], xc_ref[:, c],
-                                  preferred_element_type=jnp.float32))
-                    hc_ref[:, c] += jnp.dot(
-                        tc_ref[0], xc_ref[:, c],
-                        preferred_element_type=jnp.float32)
+                    val_ref[:, c] += (_dot(tv_ref[0], xv_ref[:, c])
+                                      + _dot(tb_ref[0], xc_ref[:, c]))
+                    hc_ref[:, c] += _dot(tc_ref[0], xc_ref[:, c])
         return kernel
 
     reduce = jnp.min if mode == "min" else jnp.max
@@ -343,8 +336,7 @@ def _make_combine_kernel_mq(mode: str, identity: float, num_queries: int):
                 contrib = tb_ref[0] + xv_ref[:, c][None, :]      # [T, T]
                 val_ref[:, c] = comb(val_ref[:, c],
                                      reduce(contrib, axis=1))
-                hc_ref[:, c] += jnp.dot(tc_ref[0], xc_ref[:, c],
-                                        preferred_element_type=jnp.float32)
+                hc_ref[:, c] += _dot(tc_ref[0], xc_ref[:, c])
     return kernel
 
 
@@ -408,7 +400,7 @@ def block_csr_combine_mq(row_ptr, tile_idx, tile_col, row_cnt,
         out_shape=[jax.ShapeDtypeStruct((n_rows * t, nq), jnp.float32),
                    jax.ShapeDtypeStruct((n_rows * t, nq), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(row_ptr, tile_idx, tile_col, row_cnt, *tensors)
 

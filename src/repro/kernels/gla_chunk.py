@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.csr_spmv import CompilerParams, default_interpret
+from repro.kernels.csr_spmv import default_interpret
 
 
 def _kernel(q_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, s_scratch,
@@ -110,7 +110,7 @@ def gla_chunked(q, k, v, w, u=None, *, chunk: int = 64,
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(q, k, v, w, u)
     return y, s

@@ -1,9 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to backend auto-detection: kernels execute via the
-Pallas interpreter off-TPU (e.g. this CPU container) and lower through
-Mosaic on real TPU, so benchmarks measure the compiled kernel where it
-exists.  ``REPRO_PALLAS_COMPILE=1`` forces compilation everywhere.
+``interpret`` defaults to the backend: kernels execute via the Pallas
+interpreter off-TPU and lower through Mosaic on a TPU, so benchmarks
+measure the compiled kernel where it exists.
 """
 from __future__ import annotations
 

@@ -30,9 +30,13 @@ Two Pallas kernels carry the per-byte work:
   mask), the pair-delta cumsums, and the run-structure restores, where a
   scatter + running-max forward fill replaces numpy's ``repeat``.
 
+Byte streams travel as ``[nb, 1, BLK]`` arrays with ``(1, 1, BLK)``
+blocks: Mosaic tiles a block's last two dims by (8, 128) unless they span
+the whole array dim, which a ``(1, BLK)`` block of an ``[nb, BLK]`` array
+does not.
+
 Everything composes under jit; ``interpret`` auto-selects exactly like
-:mod:`repro.kernels.csr_spmv` (interpret off-TPU, compile on TPU,
-``REPRO_PALLAS_COMPILE=1`` forces compilation).
+:mod:`repro.kernels.csr_spmv` (interpret off-TPU, compile on TPU).
 """
 from __future__ import annotations
 
@@ -43,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.csr_spmv import CompilerParams, default_interpret
+from repro.kernels.csr_spmv import default_interpret
 from repro.utils import ceil_div
 
 _SCAN_BLK = 512         # lanes-multiple scan block
@@ -56,7 +60,7 @@ _HALO = 4               # an int32-domain varint spans <= 5 bytes
 # ---------------------------------------------------------------------------
 
 def _make_scan_kernel(mode: str):
-    """One grid step scans one [1, BLK] block and threads the carry through
+    """One grid step scans one [1, 1, BLK] block and threads the carry through
     an SMEM scalar; within the block a log-step shift-combine (the register
     form of blocked_cumsum's "cumsum within blocks") avoids a serial loop.
     Identity/carry seed is 0 for both modes — ``max`` therefore assumes
@@ -70,7 +74,7 @@ def _make_scan_kernel(mode: str):
         def _():
             carry_ref[0, 0] = 0
 
-        x = x_ref[...]                               # [1, BLK] int32
+        x = x_ref[0]                                 # [1, BLK] int32
         blk = x.shape[1]
         ii = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
         s = 1
@@ -78,7 +82,7 @@ def _make_scan_kernel(mode: str):
             x = comb(x, jnp.where(ii >= s, jnp.roll(x, s, axis=1), 0))
             s *= 2
         out = comb(x, carry_ref[0, 0])
-        out_ref[...] = out
+        out_ref[0] = out
         carry_ref[0, 0] = out[0, blk - 1]
 
     return kernel
@@ -99,17 +103,19 @@ def blocked_scan(x: jnp.ndarray, *, mode: str = "add",
     n = x.shape[0]
     blk = _SCAN_BLK
     nb = max(1, ceil_div(n, blk))
-    x2 = jnp.pad(x.astype(jnp.int32), (0, nb * blk - n)).reshape(nb, blk)
+    x3 = jnp.pad(x.astype(jnp.int32), (0, nb * blk - n)).reshape(nb, 1, blk)
+    spec = pl.BlockSpec((1, 1, blk), lambda i: (i, 0, 0))
     out = pl.pallas_call(
         _make_scan_kernel(mode),
         grid=(nb,),
-        in_specs=[pl.BlockSpec((1, blk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, blk), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, blk), jnp.int32),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((nb, 1, blk), jnp.int32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.int32)],
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
-    )(x2)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(x3)
     return out.reshape(-1)[:n]
 
 
@@ -127,8 +133,8 @@ def _decode_kernel(cur_ref, prev_ref, term_ref, val_ref):
     that clamps ``gpos`` at the stream start, and since group reads only
     go back ``gpos`` bytes, the aliased bytes are never selected."""
     i = pl.program_id(0)
-    cur = cur_ref[...]                               # [1, BLK] bytes as i32
-    prev = prev_ref[...]
+    cur = cur_ref[0]                                 # [1, BLK] bytes as i32
+    prev = prev_ref[0]
     blk = cur.shape[1]
     ext = jnp.concatenate([prev[:, blk - _HALO:], cur], axis=1)
     gext = (i * blk - _HALO
@@ -152,28 +158,30 @@ def _decode_kernel(cur_ref, prev_ref, term_ref, val_ref):
         sh = (7 * jnp.maximum(gpos - d, 0)).astype(jnp.uint32)
         val = val + jnp.where(d <= gpos, jax.lax.shift_left(g, sh),
                               jnp.uint32(0))
-    term_ref[...] = term_ext[:, _HALO:].astype(jnp.int32)
-    val_ref[...] = val.astype(jnp.int32)
+    term_ref[0] = term_ext[:, _HALO:].astype(jnp.int32)
+    val_ref[0] = val.astype(jnp.int32)
 
 
 def _byte_stencil(b: jnp.ndarray, *, interpret: bool):
     """b: int32 [nb * _DEC_BLK] byte stream -> (term [N], val [N]) int32."""
     nb = b.shape[0] // _DEC_BLK
-    b2 = b.reshape(nb, _DEC_BLK)
+    b3 = b.reshape(nb, 1, _DEC_BLK)
+    spec = pl.BlockSpec((1, 1, _DEC_BLK), lambda i: (i, 0, 0))
     term, val = pl.pallas_call(
         _decode_kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, _DEC_BLK), lambda i: (i, 0)),
-            pl.BlockSpec((1, _DEC_BLK), lambda i: (jnp.maximum(i - 1, 0), 0)),
+            spec,
+            pl.BlockSpec((1, 1, _DEC_BLK),
+                         lambda i: (jnp.maximum(i - 1, 0), 0, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, _DEC_BLK), lambda i: (i, 0)),
-                   pl.BlockSpec((1, _DEC_BLK), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, _DEC_BLK), jnp.int32),
-                   jax.ShapeDtypeStruct((nb, _DEC_BLK), jnp.int32)],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, _DEC_BLK), jnp.int32),
+                   jax.ShapeDtypeStruct((nb, 1, _DEC_BLK), jnp.int32)],
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
-    )(b2, b2)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(b3, b3)
     return term.reshape(-1), val.reshape(-1)
 
 
@@ -273,15 +281,18 @@ def dst_delta_restore(res: jnp.ndarray, start_mask: jnp.ndarray, base,
     """Residue stream + run-start mask -> dst int32 — the device twin of
     codec.dst_delta_restore.
 
-    csum[j] - csum[start_of_run(j) - 1] telescopes the in-run deltas; the
-    per-run "residues before" value is recovered by scattering
-    csum - res at run heads and forward-filling with a max-scan (valid
-    because residues are nonnegative, so csum — and with it the run-head
-    values — is non-decreasing).  Entries beyond ``n_e`` are zeroed."""
+    csum[j] - csum[start_of_run(j) - 1] telescopes the in-run deltas.  The
+    run head of every edge is found by forward-filling head *positions*
+    with a max-scan, and the "residues before" value is gathered from
+    there.  The running sum of a large chunk passes 2**31 (every run
+    restarts at its batch offset), so csum may wrap; positions never do,
+    and the in-run difference of two wrapped int32 sums is still exact.
+    Entries beyond ``n_e`` are zeroed."""
     if interpret is None:
         interpret = default_interpret()
+    pos = jnp.arange(res.shape[0], dtype=jnp.int32)
     csum = blocked_scan(res, mode="add", interpret=interpret)
-    before = jnp.where(start_mask > 0, csum - res, 0)
-    prop = blocked_scan(before, mode="max", interpret=interpret)
-    keep = jnp.arange(res.shape[0], dtype=jnp.int32) < n_e
-    return jnp.where(keep, base + csum - prop, 0)
+    head = blocked_scan(jnp.where(start_mask > 0, pos, 0), mode="max",
+                        interpret=interpret)
+    before = (csum - res)[head]
+    return jnp.where(pos < n_e, base + csum - before, 0)

@@ -44,6 +44,11 @@ import numpy as np
 
 FAULT_EXIT = 42     # mirrored from repro.runtime.faults (importable cheaply)
 
+# Ranks are host-only by design: W rank processes on one host would race
+# for its accelerator, which belongs to one process at a time.  Every rank
+# pins JAX to the CPU, so device decode stays off there (DESIGN.md §10).
+RANK_PLATFORM = "cpu"
+
 
 # ---------------------------------------------------------------------------
 # Worker side
@@ -107,6 +112,10 @@ def _assemble_values(ctx, two, worker_of, values) -> np.ndarray:
 
 
 def worker_main(spec_path: str, rank: int) -> None:
+    import jax
+    from repro.utils import enable_compile_cache
+    jax.config.update("jax_platforms", RANK_PLATFORM)
+    enable_compile_cache()
     with open(spec_path) as f:
         spec = json.load(f)
     from repro.core import Engine, EngineConfig

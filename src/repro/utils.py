@@ -15,6 +15,25 @@ import jax
 import numpy as np
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache
+    there and nothing is set here.  Otherwise the cache lives at the fixed
+    path ``<checkout>/.jax_cache`` (git-ignored): the directory is part of
+    what a later process must find again, so it never depends on a temp
+    name, a PID or the time."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 class IntegrityError(RuntimeError):
     """A stored or transmitted artifact failed its checksum.
 

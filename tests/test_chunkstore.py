@@ -362,3 +362,26 @@ def test_ooc_config_validation(built):
                store=store)
     with pytest.raises(ValueError, match="executor"):
         Engine(dg, fm, EngineConfig(executor="bogus"))
+
+
+def test_modeled_read_bytes_stay_exact_past_float32_integers():
+    """Per-chunk prices are float32; their per-destination sum must not be,
+    or a store past 2**24 bytes per call fails its measured == model
+    audit by rounding alone."""
+    import types
+    from repro.core.executor import _dispatch_schedule_one_dest
+    b_cnt, per = 3, 2**23 + 1            # exact in float32, the sum is not
+    price = np.full((1, 1, b_cnt), per, np.int64)
+    source = types.SimpleNamespace(
+        dcsr_part=np.zeros((1, b_cnt), np.int32),
+        dcsr_batch=np.arange(b_cnt, dtype=np.int32)[None],
+        dcsr_src=np.zeros((1, b_cnt), np.int32),
+        dcsr_valid=np.ones((1, b_cnt), bool),
+        dcsr_ptr=np.arange(b_cnt + 1, dtype=np.int32)[None, None],
+        has_csr=np.zeros((1, 1, b_cnt), bool),
+        csr_bytes=price, dcsr_bytes=price, dcsr_delta_bytes=price + 1,
+        csr_raw_bytes=price, dcsr_raw_bytes=price)
+    cd, _, _ = _dispatch_schedule_one_dest(
+        source, 0, np.ones((1, 4), bool), np.array([4.0], np.float32),
+        1024.0, True)
+    assert cd["edge_read_bytes"] == b_cnt * per

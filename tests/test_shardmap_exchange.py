@@ -28,7 +28,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import sparse_collectives as sc
-from repro.core.executor import shard_map_compat
 
 mesh = jax.make_mesh((8,), ("part",))
 PCNT, V = 8, 96
@@ -41,9 +40,9 @@ def _s1(out):
 
 
 def shmap(fn, *args):
-    wrapped = jax.jit(shard_map_compat(
+    wrapped = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(P("part") for _ in args),
-        out_specs=P("part")))
+        out_specs=P("part"), check_vma=False))
     return wrapped(*args)
 
 

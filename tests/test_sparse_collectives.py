@@ -123,7 +123,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from hypothesis import given, settings, strategies as st
 from repro.core import sparse_collectives as sc
-from repro.core.executor import shard_map_compat
 
 mesh = jax.make_mesh((8,), ("part",))
 PCNT = 8
@@ -131,9 +130,9 @@ SETTINGS = settings(max_examples=8, deadline=None)
 
 
 def shmap(fn, *args):
-    wrapped = jax.jit(shard_map_compat(
+    wrapped = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(P("part") for _ in args),
-        out_specs=P("part")))
+        out_specs=P("part"), check_vma=False))
     return wrapped(*args)
 
 

@@ -116,3 +116,41 @@ def test_uniform_graph_pagerank():
     pr, _ = alg.pagerank(eng, num_iters=4)
     ref = alg.ref_pagerank(g.num_vertices, g.src, g.dst, 4)
     np.testing.assert_allclose(pr, ref, rtol=1e-4, atol=1e-7)
+
+
+def _loop_bfs(n, src, dst, source):
+    """Vertex-at-a-time BFS: the plain form ``alg.ref_bfs`` vectorizes."""
+    level = np.full(n, np.finfo(np.float32).max, np.float32)
+    level[source] = 0
+    adj = [[] for _ in range(n)]
+    for s, d in zip(src.tolist(), dst.tolist()):
+        adj[s].append(d)
+    frontier, d = [source], 0
+    while frontier:
+        d += 1
+        nxt = set()
+        for v in frontier:
+            for u in adj[v]:
+                if level[u] > d:
+                    level[u] = d
+                    nxt.add(u)
+        frontier = sorted(nxt)
+    return level
+
+
+@pytest.mark.parametrize("graph", [
+    rmat_graph(9, 8, seed=4), chain_graph(50), star_graph(40),
+    uniform_graph(300, 900, seed=2)], ids=["rmat", "chain", "star",
+                                           "uniform"])
+def test_reference_oracles_match_plain_loops(graph):
+    n, src, dst = graph.num_vertices, graph.src, graph.dst
+    for root in (0, n // 2, n - 1):
+        np.testing.assert_array_equal(alg.ref_bfs(n, src, dst, root),
+                                      _loop_bfs(n, src, dst, root))
+    rank = np.full(n, 1.0 / n)
+    outdeg = np.maximum(np.bincount(src, minlength=n), 1)
+    for _ in range(5):
+        acc = np.zeros(n)
+        np.add.at(acc, dst, rank[src] / outdeg[src])
+        rank = (1 - 0.85) / n + 0.85 * acc
+    np.testing.assert_array_equal(alg.ref_pagerank(n, src, dst, 5), rank)

@@ -4,8 +4,8 @@ store decode identical to the host decode chunk by chunk, and the
 ``EngineConfig.device_decode`` knob bit-identical on/off across all four
 executors (including ``parallel_workers``) with ``verify_io`` holding.
 
-Kernels run in interpret mode by default (the CI environment);
-``REPRO_PALLAS_COMPILE=1`` re-runs the core parity cases compiled.
+Kernels run in interpret mode off-TPU; on a TPU backend the compiled
+parity case runs too.
 
 Run standalone by ``scripts/ci.sh`` as the device-decode parity gate.
 """
@@ -24,6 +24,7 @@ from repro.core import algorithms as alg
 from repro.core.chunkstore import REP_CSR, REP_DCSR, REP_DCSR_DELTA
 from repro.data.graphs import rmat_graph
 from repro.kernels import varint as vk
+from repro.kernels.csr_spmv import default_interpret
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -154,6 +155,19 @@ if HAVE_HYPOTHESIS:
         np.testing.assert_array_equal(np.asarray(d2)[:n_e], dst)
 
 
+def test_dst_restore_survives_int32_wrap_of_the_running_sum():
+    # every run restarts at its batch offset, so a chunk with many runs of
+    # large residues sums past 2**31 while each dst stays small
+    n_runs, base = 6000, 64
+    dst = base + np.repeat(np.arange(n_runs) % 7 + 2**20, 1)
+    starts = np.arange(n_runs)
+    res = codec.dst_delta_values(dst, starts, base)
+    assert res.astype(np.int64).sum() > 2**31
+    smask = np.ones(n_runs, np.int32)
+    got = vk.dst_delta_restore(res.astype(np.int32), smask, base, n_runs)
+    np.testing.assert_array_equal(np.asarray(got), dst)
+
+
 def test_expand_csr_index_matches_repeat():
     rng = np.random.default_rng(1)
     v_src, vpad = 37, 48
@@ -171,10 +185,9 @@ def test_expand_csr_index_matches_repeat():
     np.testing.assert_array_equal(np.asarray(smask), exp_mask)
 
 
-@pytest.mark.skipif(os.environ.get("REPRO_PALLAS_COMPILE", "") != "1",
-                    reason="compiled-kernel parity needs "
-                           "REPRO_PALLAS_COMPILE=1 (real backend)")
 def test_varint_kernel_compiled_parity():
+    if default_interpret():
+        pytest.skip("compiled-kernel parity needs a TPU backend")
     rng = np.random.default_rng(2)
     vals = rng.integers(0, INT32_MAX, 4096).astype(np.uint64)
     np.testing.assert_array_equal(
