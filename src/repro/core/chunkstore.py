@@ -44,6 +44,7 @@ in-memory bitmaps, they are control state, not bulk data.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import mmap
@@ -56,6 +57,7 @@ import numpy as np
 
 from repro.core import codec
 from repro.core.formats import ChunkFormats
+from repro.core.tracing import span
 from repro.core.partition import DistGraph
 from repro.utils import (IntegrityError, atomic_write_json, ceil_div, crc32,
                          json_crc, token_ctx)
@@ -1046,6 +1048,18 @@ class VertexSpill:
         """Zero-copy [P, v_max] views of the authoritative on-disk state."""
         return {name: mm[:, :self.v_max] for name, mm in self._mm.items()}
 
+    @contextlib.contextmanager
+    def _io_span(self, name: str):
+        """A ``dfo.spill.*`` span carrying the measured bytes moved in
+        it."""
+        before = self.bytes_read + self.bytes_written
+        with span(name) as sp:
+            try:
+                yield
+            finally:
+                sp.set_metadata(
+                    bytes=self.bytes_read + self.bytes_written - before)
+
     def _batch_runs(self, batch_mask: np.ndarray) -> list:
         """Coalesce touched batches into per-row contiguous column spans
         ``(p, lo, hi)`` — one slice per run instead of one per batch, so a
@@ -1073,14 +1087,16 @@ class VertexSpill:
         out = {}
         touched = int(batch_mask.sum())
         runs = self._batch_runs(batch_mask)
-        for name in (self._mm if keys is None else keys):
-            mm = self._mm[name]
-            self._crc_verify(name, runs)
-            arr = np.zeros((self.p_cnt, self.v_pad), mm.dtype)
-            for p, lo, hi in runs:
-                arr[p, lo:hi] = mm[p, lo:hi]
-            out[name] = arr
-            self.bytes_read += touched * self.batch_size * mm.dtype.itemsize
+        with self._io_span("spill.read"):
+            for name in (self._mm if keys is None else keys):
+                mm = self._mm[name]
+                self._crc_verify(name, runs)
+                arr = np.zeros((self.p_cnt, self.v_pad), mm.dtype)
+                for p, lo, hi in runs:
+                    arr[p, lo:hi] = mm[p, lo:hi]
+                out[name] = arr
+                self.bytes_read += (touched * self.batch_size
+                                    * mm.dtype.itemsize)
         return out
 
     def write(self, updates: dict[str, np.ndarray], batch_mask: np.ndarray
@@ -1089,32 +1105,41 @@ class VertexSpill:
         (or [P, v_max]) arrays."""
         touched = int(batch_mask.sum())
         runs = self._batch_runs(batch_mask)
-        for name, arr in updates.items():
-            mm = self._mm[name]
-            arr = np.asarray(arr, mm.dtype)
-            if arr.shape[1] != self.v_pad:
-                pad = np.zeros((self.p_cnt, self.v_pad), mm.dtype)
-                pad[:, :arr.shape[1]] = arr
-                arr = pad
-            for p, lo, hi in runs:
-                mm[p, lo:hi] = arr[p, lo:hi]
-            self._crc_update(name, runs)
-            self.bytes_written += (touched * self.batch_size
-                                   * mm.dtype.itemsize)
+        with self._io_span("spill.write"):
+            for name, arr in updates.items():
+                mm = self._mm[name]
+                arr = np.asarray(arr, mm.dtype)
+                if arr.shape[1] != self.v_pad:
+                    pad = np.zeros((self.p_cnt, self.v_pad), mm.dtype)
+                    pad[:, :arr.shape[1]] = arr
+                    arr = pad
+                for p, lo, hi in runs:
+                    mm[p, lo:hi] = arr[p, lo:hi]
+                self._crc_update(name, runs)
+                self.bytes_written += (touched * self.batch_size
+                                       * mm.dtype.itemsize)
 
     def merge_write(self, padded_state: dict[str, np.ndarray],
                     updates: dict[str, np.ndarray], mask: np.ndarray,
                     batch_mask: np.ndarray) -> None:
         """Masked update + measured write-back, the one shared path for
         ProcessEdges apply and ProcessVertices: ``np.where(mask, update,
-        old)`` into the padded arrays previously returned by :meth:`read`,
+        old)`` over the padded arrays previously returned by :meth:`read`,
         then write the touched batches.  ``mask``/``updates`` are [P, v_max];
-        arrays without an update are written back unchanged."""
-        for name, v in updates.items():
-            av = padded_state[name]
-            av[:, :self.v_max] = np.where(mask, np.asarray(v, av.dtype),
-                                          av[:, :self.v_max])
-        self.write(padded_state, batch_mask)
+        arrays without an update are written back unchanged.  The merge
+        goes to new arrays, never into ``padded_state``: on the CPU
+        ``jnp.asarray`` of a host array may alias it, and a computation
+        still pending on it (the apply's ``ret`` or new-active mask) must
+        read the old values.  Its span holds the inner :meth:`write`'s,
+        which carries the bytes."""
+        with span("spill.write"):
+            merged = dict(padded_state)
+            for name, v in updates.items():
+                old = padded_state[name]
+                merged[name] = old.copy()
+                merged[name][:, :self.v_max] = np.where(
+                    mask, np.asarray(v, old.dtype), old[:, :self.v_max])
+            self.write(merged, batch_mask)
 
     # -- active bitmap -------------------------------------------------------
     def bitmap_nbytes(self) -> int:
@@ -1126,27 +1151,30 @@ class VertexSpill:
         checkpointed bitmap is control-plane motion, not modeled I/O —
         the replayed op then re-issues the exact measured requests the
         failure-free run would have."""
-        packed = np.packbits(np.asarray(mask, bool), axis=1)
-        with open(os.path.join(self.root, f"{name}.bits"), "wb") as f:
-            f.write(packed.tobytes())
-        with open(os.path.join(self.root, f"{name}.bits.crc"), "w") as f:
-            f.write(str(crc32(packed)))
-        if measured:
-            self.bytes_written += packed.nbytes
+        with self._io_span("spill.write"):
+            packed = np.packbits(np.asarray(mask, bool), axis=1)
+            with open(os.path.join(self.root, f"{name}.bits"), "wb") as f:
+                f.write(packed.tobytes())
+            with open(os.path.join(self.root, f"{name}.bits.crc"),
+                      "w") as f:
+                f.write(str(crc32(packed)))
+            if measured:
+                self.bytes_written += packed.nbytes
 
     def read_bitmap(self, name: str = "active",
                     measured: bool = True) -> np.ndarray | None:
         path = os.path.join(self.root, f"{name}.bits")
         row = ceil_div(self.v_max, 8)
-        if not os.path.exists(path):
+        with self._io_span("spill.read"):
+            if not os.path.exists(path):
+                if measured:  # fresh file reads zeros
+                    self.bytes_read += self.p_cnt * row
+                return None
+            packed = np.fromfile(path, np.uint8).reshape(self.p_cnt, row)
+            self._verify_bitmap(name, path, packed)
             if measured:
-                self.bytes_read += self.p_cnt * row  # fresh file reads zeros
-            return None
-        packed = np.fromfile(path, np.uint8).reshape(self.p_cnt, row)
-        self._verify_bitmap(name, path, packed)
-        if measured:
-            self.bytes_read += packed.nbytes
-        return np.unpackbits(packed, axis=1)[:, :self.v_max].astype(bool)
+                self.bytes_read += packed.nbytes
+            return np.unpackbits(packed, axis=1)[:, :self.v_max].astype(bool)
 
     def _verify_bitmap(self, name: str, path: str,
                        packed: np.ndarray) -> None:
@@ -1395,13 +1423,14 @@ class ChunkPrefetcher:
         """Blocking put that aborts when the consumer closed the pipeline
         (so an abandoned iteration never strands the worker on a full
         queue, leaking the thread + its decoded buffers)."""
-        while not self._stop.is_set():
-            try:
-                self._queue.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
-        return False
+        with span("chunk.put_wait"):
+            while not self._stop.is_set():
+                try:
+                    self._queue.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
     def _run(self):
         try:
@@ -1412,28 +1441,34 @@ class ChunkPrefetcher:
                             return
                         continue
                     q, k, chunks = item
+                    n = len(chunks)
                     # Fetch bytes first, token-free (C-level copy / kernel
                     # page faults); only the numpy decode takes the token.
-                    raw = [(p, rep,
-                            self._source.read_chunk_bytes(q, p, k, rep))
-                           for p, rep in chunks]
-                    if self._device_decode:
-                        # Device decode: jit dispatches, GIL released while
-                        # the kernels run — no compute token needed.
-                        decoded = [
-                            (p, self._source.decode_chunk_device(
-                                q, p, k, rep, index, payload), nb)
-                            for p, rep, (index, payload, nb) in raw]
-                        work = self._assemble(q, k, decoded, len(chunks),
-                                              n_device=len(chunks))
-                    else:
-                        with self._lock_ctx:   # token held: decode burst
+                    with span("chunk.read", q=q, k=k, chunks=n) as sp:
+                        raw = [(p, rep,
+                                self._source.read_chunk_bytes(q, p, k, rep))
+                               for p, rep in chunks]
+                        sp.set_metadata(
+                            bytes=sum(nb for _, _, (_, _, nb) in raw))
+                    with span("chunk.decode", q=q, k=k, chunks=n,
+                              device=int(self._device_decode)) as sp:
+                        if self._device_decode:
+                            # Device decode: jit dispatches, GIL released
+                            # while the kernels run — no compute token.
                             decoded = [
-                                (p, self._source.decode_chunk(
+                                (p, self._source.decode_chunk_device(
                                     q, p, k, rep, index, payload), nb)
                                 for p, rep, (index, payload, nb) in raw]
-                            work = self._assemble(q, k, decoded,
-                                                  len(chunks))
+                            work = self._assemble(q, k, decoded, n,
+                                                  n_device=n)
+                        else:
+                            with self._lock_ctx:  # token held: decode burst
+                                decoded = [
+                                    (p, self._source.decode_chunk(
+                                        q, p, k, rep, index, payload), nb)
+                                    for p, rep, (index, payload, nb) in raw]
+                                work = self._assemble(q, k, decoded, n)
+                        sp.set_metadata(edges=int(work.src.size))
                     if not self._put(work):  # token released: may block
                         return
                 self._put(self._DONE)
@@ -1461,7 +1496,8 @@ class ChunkPrefetcher:
     def __iter__(self) -> Iterator[BatchWork]:
         try:
             while True:
-                item = self._queue.get()
+                with span("ooc.stream_wait"):
+                    item = self._queue.get()
                 if item is self._DONE:
                     return
                 if isinstance(item, BaseException):

@@ -68,6 +68,7 @@ from repro.core.chunkstore import (
 )
 from repro.core.formats import BlockTilesHost
 from repro.core.partition import row_block_batch_map
+from repro.core.tracing import span
 from repro.kernels.csr_spmv import (
     block_csr_combine, build_tile_struct, default_interpret,
 )
@@ -180,50 +181,53 @@ def _dest_phases(d, recv_msg, recv_mask, *, slot_fn, monoid, spec, cfg,
     per-edge arrays for the segment backend or tile arrays for block_csr).
     Returns (agg [V], has [V], counter contributions dict)."""
     v_max, b_cnt = spec.v_max, spec.num_batches
-    chunk_active, dispatched = phases.dispatch_one_dest(
-        d["dcsr_src"], d["dcsr_part"], d["dcsr_batch"], d["dcsr_valid"],
-        recv_mask, v_max, b_cnt)
-    c = {"msgs_dispatched": dispatched,
-         "chunks_read": jnp.sum(chunk_active, dtype=jnp.float32)}
-    if cfg.enable_adaptive_formats:
-        msgs_from = jnp.sum(recv_mask, axis=1).astype(jnp.int32)
-        c.update(phases.format_choice_one_dest(
-            d["dcsr_ptr"], d["has_csr"], d["csr_bytes"], d["dcsr_bytes"],
-            d["dcsr_delta_bytes"], d["csr_raw_bytes"], d["dcsr_raw_bytes"],
-            part_sizes, gamma, msgs_from, cfg.compression, chunk_active))
-    else:
-        # Non-adaptive baseline: CSR for every chunk (the behavior the
-        # paper improves on; model-only — ooc executors reject this
-        # config).  The CSR family still follows cfg.compression so the
-        # disk and wire counters of one run price one layout; the raw
-        # twin keeps the fully-legacy number either way.
-        base = d["csr_bytes"] if cfg.compression else d["csr_raw_bytes"]
-        c["seek_cost"] = jnp.zeros((), jnp.float32)
-        c["edge_read_bytes"] = jnp.sum(
-            jnp.where(chunk_active, base, 0.0), dtype=jnp.float32)
-        c["edge_read_bytes_raw"] = jnp.sum(
-            jnp.where(chunk_active, d["csr_raw_bytes"], 0.0),
-            dtype=jnp.float32)
-        c["chunks_read_csr"] = c["chunks_read"]
-        c["chunks_read_dcsr"] = jnp.zeros((), jnp.float32)
-        c["chunks_read_dcsr_delta"] = jnp.zeros((), jnp.float32)
+    with jax.named_scope("dispatch"):
+        chunk_active, dispatched = phases.dispatch_one_dest(
+            d["dcsr_src"], d["dcsr_part"], d["dcsr_batch"], d["dcsr_valid"],
+            recv_mask, v_max, b_cnt)
+        c = {"msgs_dispatched": dispatched,
+             "chunks_read": jnp.sum(chunk_active, dtype=jnp.float32)}
+        if cfg.enable_adaptive_formats:
+            msgs_from = jnp.sum(recv_mask, axis=1).astype(jnp.int32)
+            c.update(phases.format_choice_one_dest(
+                d["dcsr_ptr"], d["has_csr"], d["csr_bytes"],
+                d["dcsr_bytes"], d["dcsr_delta_bytes"], d["csr_raw_bytes"],
+                d["dcsr_raw_bytes"], part_sizes, gamma, msgs_from,
+                cfg.compression, chunk_active))
+        else:
+            # Non-adaptive baseline: CSR for every chunk (the behavior the
+            # paper improves on; model-only — ooc executors reject this
+            # config).  The CSR family still follows cfg.compression so
+            # the disk and wire counters of one run price one layout; the
+            # raw twin keeps the fully-legacy number either way.
+            base = d["csr_bytes"] if cfg.compression else d["csr_raw_bytes"]
+            c["seek_cost"] = jnp.zeros((), jnp.float32)
+            c["edge_read_bytes"] = jnp.sum(
+                jnp.where(chunk_active, base, 0.0), dtype=jnp.float32)
+            c["edge_read_bytes_raw"] = jnp.sum(
+                jnp.where(chunk_active, d["csr_raw_bytes"], 0.0),
+                dtype=jnp.float32)
+            c["chunks_read_csr"] = c["chunks_read"]
+            c["chunks_read_dcsr"] = jnp.zeros((), jnp.float32)
+            c["chunks_read_dcsr_delta"] = jnp.zeros((), jnp.float32)
 
-    if backend == "segment":
-        agg, has, touched = phases.process_segment_one_dest(
-            d["edge_src_part"], d["edge_src_local"], d["edge_dst_local"],
-            d["edge_data"], d["edge_valid"], recv_msg, recv_mask,
-            slot_fn, monoid, v_max)
-    else:
-        bt = {k: d[k] for k in ("slot_row", "slot_col", "slot_part",
-                                "slot_valid", "row_ptr", "tiles_cnt")}
-        vals = {"mode": mode_meta[0], "a": mode_meta[1],
-                "tiles_v": d.get("tiles_v"), "tiles_b": d.get("tiles_b")}
-        agg, has, touched = phases.process_block_one_dest(
-            bt, vals, recv_msg, recv_mask, chunk_active, monoid, rb_map,
-            tile=bt_static.tile, v_pad=bt_static.v_pad,
-            n_rows=bt_static.n_rows,
-            max_tiles_per_row=bt_static.max_tiles_per_row,
-            interpret=interpret)
+    with jax.named_scope("combine"):
+        if backend == "segment":
+            agg, has, touched = phases.process_segment_one_dest(
+                d["edge_src_part"], d["edge_src_local"], d["edge_dst_local"],
+                d["edge_data"], d["edge_valid"], recv_msg, recv_mask,
+                slot_fn, monoid, v_max)
+        else:
+            bt = {k: d[k] for k in ("slot_row", "slot_col", "slot_part",
+                                    "slot_valid", "row_ptr", "tiles_cnt")}
+            vals = {"mode": mode_meta[0], "a": mode_meta[1],
+                    "tiles_v": d.get("tiles_v"), "tiles_b": d.get("tiles_b")}
+            agg, has, touched = phases.process_block_one_dest(
+                bt, vals, recv_msg, recv_mask, chunk_active, monoid, rb_map,
+                tile=bt_static.tile, v_pad=bt_static.v_pad,
+                n_rows=bt_static.n_rows,
+                max_tiles_per_row=bt_static.max_tiles_per_row,
+                interpret=interpret)
     c["edges_touched"] = touched
     return agg, has, c
 
@@ -288,39 +292,43 @@ def make_local_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
         counters = _zero_counters(counter_keys)
         amask = g.vertex_valid if active is None else (active & g.vertex_valid)
         # Phase 1: generate
-        msg = signal_fn(state, global_id)                        # [P, V]
-        m_p = jnp.sum(amask, axis=1, dtype=jnp.float32)          # [P]
-        counters["msgs_generated"] = jnp.sum(m_p)
-        counters["msg_disk_bytes"] = jnp.sum(m_p) * (cfg.msg_bytes + 4)
+        with jax.named_scope("generate"):
+            msg = signal_fn(state, global_id)                    # [P, V]
+            m_p = jnp.sum(amask, axis=1, dtype=jnp.float32)      # [P]
+            counters["msgs_generated"] = jnp.sum(m_p)
+            counters["msg_disk_bytes"] = jnp.sum(m_p) * (cfg.msg_bytes + 4)
 
         # Phase 2: filter + pass, built receive-major per destination —
         # no dense [P, P, V] broadcast of amask, no send-major transpose.
-        recv_mask = jax.vmap(
-            lambda a_, n_, nc_, mm: phases.filter_sendmask(
-                a_, n_, nc_, mm, cfg),
-            in_axes=(0, 0, 0, 0), out_axes=1)(
-            amask, g.need, g.need_counts, m_p)                   # [Q, P, V]
-        recv_msg = jnp.where(recv_mask, msg[None, :, :], 0)
-        total_sent = jnp.sum(recv_mask, dtype=jnp.float32)
-        n_active = jnp.sum(amask, dtype=jnp.float32)
-        counters["msgs_sent"] = total_sent
-        counters["msgs_sent_nofilter"] = p_cnt * n_active
-        # Network model from the routing structure: each nonempty off-node
-        # (p, q) message batch is priced at its adaptive wire encoding
-        # (three-way — incl. the delta-varint vpairs, whose data-dependent
-        # index size comes from the same masks — when compression is on).
-        counts = phases.routing_counts(recv_mask)                # [Q, P]
-        gapb = unib = None
-        if cfg.compression:
-            gapb = codec.mask_gap_bytes(recv_mask, xp=jnp)
-            unib = phases.batch_value_uniform(recv_mask, msg[None, :, :])
-        cross = jnp.arange(p_cnt)[:, None] != jnp.arange(p_cnt)[None, :]
-        counters["net_bytes"], counters["net_bytes_raw"] = (
-            phases.net_bytes_model(counts, cross, spec.v_max,
-                                   cfg.msg_bytes, gap_bytes=gapb,
-                                   uniform=unib))
-        counters["net_bytes_nofilter"] = ((p_cnt - 1) * n_active
-                                          * (cfg.msg_bytes + 4))
+        with jax.named_scope("filter"):
+            recv_mask = jax.vmap(
+                lambda a_, n_, nc_, mm: phases.filter_sendmask(
+                    a_, n_, nc_, mm, cfg),
+                in_axes=(0, 0, 0, 0), out_axes=1)(
+                amask, g.need, g.need_counts, m_p)               # [Q, P, V]
+            recv_msg = jnp.where(recv_mask, msg[None, :, :], 0)
+            total_sent = jnp.sum(recv_mask, dtype=jnp.float32)
+            n_active = jnp.sum(amask, dtype=jnp.float32)
+            counters["msgs_sent"] = total_sent
+            counters["msgs_sent_nofilter"] = p_cnt * n_active
+            # Network model from the routing structure: each nonempty
+            # off-node (p, q) message batch is priced at its adaptive wire
+            # encoding (three-way — incl. the delta-varint vpairs, whose
+            # data-dependent index size comes from the same masks — when
+            # compression is on).
+            counts = phases.routing_counts(recv_mask)            # [Q, P]
+            gapb = unib = None
+            if cfg.compression:
+                gapb = codec.mask_gap_bytes(recv_mask, xp=jnp)
+                unib = phases.batch_value_uniform(recv_mask,
+                                                  msg[None, :, :])
+            cross = jnp.arange(p_cnt)[:, None] != jnp.arange(p_cnt)[None, :]
+            counters["net_bytes"], counters["net_bytes_raw"] = (
+                phases.net_bytes_model(counts, cross, spec.v_max,
+                                       cfg.msg_bytes, gap_bytes=gapb,
+                                       uniform=unib))
+            counters["net_bytes_nofilter"] = ((p_cnt - 1) * n_active
+                                              * (cfg.msg_bytes + 4))
 
         # Phases 3 + 4 per destination partition (in-HBM ChunkSource)
         d = HBMChunkSource.dest_arrays(fmts)
@@ -340,9 +348,10 @@ def make_local_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
             cd = {k: sum(o[2][k] for o in outs) for k in outs[0][2]}
         counters.update(cd)
 
-        new_state, new_active, total, io = _apply_and_account(
-            state, agg, has, global_id, g.vertex_valid, apply_fn, cfg,
-            spec.batch_size, amask)
+        with jax.named_scope("apply"):
+            new_state, new_active, total, io = _apply_and_account(
+                state, agg, has, global_id, g.vertex_valid, apply_fn, cfg,
+                spec.batch_size, amask)
         counters.update(io)
         return new_state, new_active, total, counters
 
@@ -444,64 +453,66 @@ def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
         vertex_valid = garrs["vertex_valid"]               # [1, V]
         amask = vertex_valid if active is None else (active & vertex_valid)
         # Phase 1: generate
-        msg = signal_fn(state, garrs["global_id"])         # [1, V]
-        m_p = jnp.sum(amask, dtype=jnp.float32)
-        counters["msgs_generated"] = m_p
-        counters["msg_disk_bytes"] = m_p * (cfg.msg_bytes + 4)
+        with jax.named_scope("generate"):
+            msg = signal_fn(state, garrs["global_id"])     # [1, V]
+            m_p = jnp.sum(amask, dtype=jnp.float32)
+            counters["msgs_generated"] = m_p
+            counters["msg_disk_bytes"] = m_p * (cfg.msg_bytes + 4)
 
         # Phase 2: filter + real interconnect exchange
-        my = jax.lax.axis_index(axis)
-        sendmask = phases.filter_sendmask(
-            amask[0], garrs["need"][0], garrs["need_counts"][0], m_p, cfg)
-        counters["msgs_sent"] = jnp.sum(sendmask, dtype=jnp.float32)
-        counters["msgs_sent_nofilter"] = p_cnt * m_p
-        # Same routing-derived network model as LOCAL (psum across shards
-        # recovers the full [Q, P] sum): per-destination batch counts,
-        # priced at the adaptive wire encoding, self-shard excluded.
-        counts = phases.routing_counts(sendmask)                 # [Q]
-        gapb = unib = None
-        if cfg.compression:
-            gapb = codec.mask_gap_bytes(sendmask, xp=jnp)
-            unib = phases.batch_value_uniform(sendmask, msg[0][None, :])
-        counters["net_bytes"], counters["net_bytes_raw"] = (
-            phases.net_bytes_model(counts, jnp.arange(p_cnt) != my,
-                                   spec.v_max, cfg.msg_bytes,
-                                   gap_bytes=gapb, uniform=unib))
-        counters["net_bytes_nofilter"] = ((p_cnt - 1) * m_p
-                                          * (cfg.msg_bytes + 4))
-        # Physical wire (DESIGN.md §12): dense slab, or the compacted
-        # collective the host arbitrated for this iteration's capacity
-        # bucket — with an in-graph overflow fallback to dense (the
-        # pmax'd predicate is identical on every shard, so the branch is
-        # uniform and the collectives stay in lockstep).  Either way the
-        # combine sees the exact dense [P, V] layout, so results are
-        # bit-identical to the legacy exchange.
-        is0 = (my == 0).astype(jnp.float32)
-        dense_elems = jnp.float32(
-            phases.net_payload_elems_model(p_cnt, spec.v_max))
-        counters["net_payload_elems_dense"] = dense_elems
-        if wire_capacity is None:
-            recv_msg, recv_mask, measured = _dense_exchange(
-                msg[0], sendmask, axis)
-            counters["net_payload_elems"] = dense_elems
-            counters["measured_net_payload_elems"] = measured
-            counters["exchange_dense_iters"] = is0
-        else:
-            overflow = jax.lax.pmax(jnp.max(counts), axis) > wire_capacity
-            recv_msg, recv_mask, measured = jax.lax.cond(
-                overflow,
-                lambda _: _dense_exchange(msg[0], sendmask, axis),
-                lambda _: _compacted_exchange(msg[0], sendmask,
-                                              wire_capacity, axis),
-                None)
-            comp_elems = jnp.float32(phases.net_payload_elems_model(
-                p_cnt, spec.v_max, capacity=wire_capacity))
-            ovf_f = overflow.astype(jnp.float32)
-            counters["net_payload_elems"] = jnp.where(
-                overflow, dense_elems, comp_elems)
-            counters["measured_net_payload_elems"] = measured
-            counters["exchange_compacted_iters"] = (1.0 - ovf_f) * is0
-            counters["exchange_dense_iters"] = ovf_f * is0
+        with jax.named_scope("filter"):
+            my = jax.lax.axis_index(axis)
+            sendmask = phases.filter_sendmask(
+                amask[0], garrs["need"][0], garrs["need_counts"][0], m_p, cfg)
+            counters["msgs_sent"] = jnp.sum(sendmask, dtype=jnp.float32)
+            counters["msgs_sent_nofilter"] = p_cnt * m_p
+            # Same routing-derived network model as LOCAL (psum across shards
+            # recovers the full [Q, P] sum): per-destination batch counts,
+            # priced at the adaptive wire encoding, self-shard excluded.
+            counts = phases.routing_counts(sendmask)             # [Q]
+            gapb = unib = None
+            if cfg.compression:
+                gapb = codec.mask_gap_bytes(sendmask, xp=jnp)
+                unib = phases.batch_value_uniform(sendmask, msg[0][None, :])
+            counters["net_bytes"], counters["net_bytes_raw"] = (
+                phases.net_bytes_model(counts, jnp.arange(p_cnt) != my,
+                                       spec.v_max, cfg.msg_bytes,
+                                       gap_bytes=gapb, uniform=unib))
+            counters["net_bytes_nofilter"] = ((p_cnt - 1) * m_p
+                                              * (cfg.msg_bytes + 4))
+            # Physical wire (DESIGN.md §12): dense slab, or the compacted
+            # collective the host arbitrated for this iteration's capacity
+            # bucket — with an in-graph overflow fallback to dense (the
+            # pmax'd predicate is identical on every shard, so the branch is
+            # uniform and the collectives stay in lockstep).  Either way the
+            # combine sees the exact dense [P, V] layout, so results are
+            # bit-identical to the legacy exchange.
+            is0 = (my == 0).astype(jnp.float32)
+            dense_elems = jnp.float32(
+                phases.net_payload_elems_model(p_cnt, spec.v_max))
+            counters["net_payload_elems_dense"] = dense_elems
+            if wire_capacity is None:
+                recv_msg, recv_mask, measured = _dense_exchange(
+                    msg[0], sendmask, axis)
+                counters["net_payload_elems"] = dense_elems
+                counters["measured_net_payload_elems"] = measured
+                counters["exchange_dense_iters"] = is0
+            else:
+                overflow = jax.lax.pmax(jnp.max(counts), axis) > wire_capacity
+                recv_msg, recv_mask, measured = jax.lax.cond(
+                    overflow,
+                    lambda _: _dense_exchange(msg[0], sendmask, axis),
+                    lambda _: _compacted_exchange(msg[0], sendmask,
+                                                  wire_capacity, axis),
+                    None)
+                comp_elems = jnp.float32(phases.net_payload_elems_model(
+                    p_cnt, spec.v_max, capacity=wire_capacity))
+                ovf_f = overflow.astype(jnp.float32)
+                counters["net_payload_elems"] = jnp.where(
+                    overflow, dense_elems, comp_elems)
+                counters["measured_net_payload_elems"] = measured
+                counters["exchange_compacted_iters"] = (1.0 - ovf_f) * is0
+                counters["exchange_dense_iters"] = ovf_f * is0
 
         # Phases 3 + 4 on this shard's destination view (in-HBM ChunkSource)
         d = {k: v[0] for k, v in HBMChunkSource.dest_arrays(garrs).items()}
@@ -518,9 +529,10 @@ def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
         counters.update(cd)
         agg, has = agg[None, :], has[None, :]
 
-        new_state, new_active, total, io = _apply_and_account(
-            state, agg, has, garrs["global_id"], vertex_valid, apply_fn,
-            cfg, spec.batch_size, amask)
+        with jax.named_scope("apply"):
+            new_state, new_active, total, io = _apply_and_account(
+                state, agg, has, garrs["global_id"], vertex_valid, apply_fn,
+                cfg, spec.batch_size, amask)
         counters.update(io)
         total = jax.lax.psum(total, axis)
         counters = {k: jax.lax.psum(v, axis) for k, v in counters.items()}
@@ -842,99 +854,113 @@ def make_ooc_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
         arrays_bytes = spill.arrays_bytes()
         bitmap = float(spill.bitmap_nbytes())
 
+        # Host spans (repro.core.tracing) cover the call phase by phase:
+        # generate, filter, dispatch, then per streamed batch the wait on
+        # the prefetch queue and the combine, then apply.
         # Phase 1: generate — read the active bitmap + active batches
-        spill.read_bitmap()                                     # measured
-        gen_batches = _batch_any(amask, bs, b_cnt)
-        gstate = {k: v[:, :v_max]
-                  for k, v in spill.read(gen_batches).items()}  # measured
-        # unread (inactive) batches hold zeros; their message values are
-        # garbage by contract (recv_mask never selects them) — silence the
-        # 0/0-style warnings that garbage can trigger in numpy signal fns
-        with np.errstate(all="ignore"):
-            msg = np.asarray(signal_fn(gstate, global_id), np.float32)
-        m_p = amask.sum(axis=1).astype(np.float64)
-        counters["msgs_generated"] = float(m_p.sum())
-        counters["msg_disk_bytes"] = float(m_p.sum()) * mb
+        with span("ooc.generate") as sp:
+            spill.read_bitmap()                                 # measured
+            gen_batches = _batch_any(amask, bs, b_cnt)
+            sp.set_metadata(batches=int(gen_batches.sum()))
+            gstate = {k: v[:, :v_max]
+                      for k, v in spill.read(gen_batches).items()}  # measured
+            # unread (inactive) batches hold zeros; their message values
+            # are garbage by contract (recv_mask never selects them) —
+            # silence the 0/0-style warnings that garbage can trigger in
+            # numpy signal fns
+            with np.errstate(all="ignore"):
+                msg = np.asarray(signal_fn(gstate, global_id), np.float32)
+            m_p = amask.sum(axis=1).astype(np.float64)
+            counters["msgs_generated"] = float(m_p.sum())
+            counters["msg_disk_bytes"] = float(m_p.sum()) * mb
 
         # Phase 2: filter (receive-major [Q, P, V]; traffic is analytic —
         # single host, nothing crosses a wire)
-        recv_mask = np.empty((p_cnt, p_cnt, v_max), bool)
-        for p in range(p_cnt):
-            recv_mask[:, p] = phases.filter_sendmask(
-                amask[p], need[p], need_counts[p], m_p[p], cfg, xp=np)
-        total_sent = float(recv_mask.sum())
-        n_active = float(amask.sum())
-        counters["msgs_sent"] = total_sent
-        counters["msgs_sent_nofilter"] = p_cnt * n_active
-        counts = phases.routing_counts(recv_mask, xp=np)         # [Q, P]
-        gapb = unib = None
-        if cfg.compression:
-            gapb = codec.mask_gap_bytes(recv_mask, xp=np)
-            unib = phases.batch_value_uniform(recv_mask, msg[None, :, :],
-                                              xp=np)
-        cross = np.arange(p_cnt)[:, None] != np.arange(p_cnt)[None, :]
-        net, net_raw = phases.net_bytes_model(
-            counts, cross, v_max, cfg.msg_bytes, gap_bytes=gapb,
-            uniform=unib, xp=np)
-        counters["net_bytes"] = float(net)
-        counters["net_bytes_raw"] = float(net_raw)
-        counters["net_bytes_nofilter"] = (p_cnt - 1) * n_active * mb
+        with span("ooc.filter") as sp:
+            recv_mask = np.empty((p_cnt, p_cnt, v_max), bool)
+            for p in range(p_cnt):
+                recv_mask[:, p] = phases.filter_sendmask(
+                    amask[p], need[p], need_counts[p], m_p[p], cfg, xp=np)
+            total_sent = float(recv_mask.sum())
+            sp.set_metadata(sent=int(total_sent))
+            n_active = float(amask.sum())
+            counters["msgs_sent"] = total_sent
+            counters["msgs_sent_nofilter"] = p_cnt * n_active
+            counts = phases.routing_counts(recv_mask, xp=np)     # [Q, P]
+            gapb = unib = None
+            if cfg.compression:
+                gapb = codec.mask_gap_bytes(recv_mask, xp=np)
+                unib = phases.batch_value_uniform(
+                    recv_mask, msg[None, :, :], xp=np)
+            cross = np.arange(p_cnt)[:, None] != np.arange(p_cnt)[None, :]
+            net, net_raw = phases.net_bytes_model(
+                counts, cross, v_max, cfg.msg_bytes, gap_bytes=gapb,
+                uniform=unib, xp=np)
+            counters["net_bytes"] = float(net)
+            counters["net_bytes_raw"] = float(net_raw)
+            counters["net_bytes_nofilter"] = (p_cnt - 1) * n_active * mb
 
         # Phases 3 + 3.5 + schedule per destination (shared helper: the
         # runtime format decision prices the model AND drives the disk
         # reads below, so measured bytes match the model by design).
-        schedule = []
-        for q in range(p_cnt):
-            cd, _, sched_q = _dispatch_schedule_one_dest(
-                source, q, recv_mask[q], part_sizes, gamma,
-                cfg.compression)
-            for ck, cv in cd.items():
-                counters[ck] += cv
-            schedule.extend(sched_q)
+        with span("ooc.dispatch") as sp:
+            schedule = []
+            for q in range(p_cnt):
+                cd, _, sched_q = _dispatch_schedule_one_dest(
+                    source, q, recv_mask[q], part_sizes, gamma,
+                    cfg.compression)
+                for ck, cv in cd.items():
+                    counters[ck] += cv
+                schedule.extend(sched_q)
+            sp.set_metadata(chunks=int(counters["chunks_read"]))
 
-        # Phase 4: stream active chunks dst-batch by dst-batch, double-
-        # buffered; combine with the monoid (numpy segment scatter) or the
-        # Pallas block-CSR kernel.
-        agg = np.full((p_cnt, v_max), identity, np.float32)
-        has = np.zeros((p_cnt, v_max), bool)
-        edges_touched = 0.0
-        if backend == "block_csr":
-            vec_cache = {}
+            # Phase 4: stream active chunks dst-batch by dst-batch, double-
+            # buffered; combine with the monoid (numpy segment scatter) or
+            # the Pallas block-CSR kernel.
+            agg = np.full((p_cnt, v_max), identity, np.float32)
+            has = np.zeros((p_cnt, v_max), bool)
+            edges_touched = 0.0
+            if backend == "block_csr":
+                vec_cache = {}
 
-            def vectors(q):
-                if q not in vec_cache:
-                    vec_cache[q] = _block_dest_vectors(
-                        recv_mask[q], msg, mode, a_const, identity, v_pad_t)
-                return vec_cache[q]
+                def vectors(q):
+                    if q not in vec_cache:
+                        vec_cache[q] = _block_dest_vectors(
+                            recv_mask[q], msg, mode, a_const, identity,
+                            v_pad_t)
+                    return vec_cache[q]
 
         for w in ChunkPrefetcher(source, schedule,
                                  depth=cfg.ooc_prefetch_depth,
                                  device_decode=engine.device_decode):
             xv_q, xc_q = (vectors(w.q) if backend == "block_csr"
                           else (None, None))
-            edges_touched += _combine_stream_batch(
-                w, recv_mask[w.q], msg, slot_fn, monoid, agg, has,
-                backend=backend, mode=mode, blk=blk, xv=xv_q, xc=xc_q,
-                v_max=v_max)
+            with span("ooc.combine", q=w.q, k=w.k, edges=int(w.src.size)):
+                edges_touched += _combine_stream_batch(
+                    w, recv_mask[w.q], msg, slot_fn, monoid, agg, has,
+                    backend=backend, mode=mode, blk=blk, xv=xv_q, xc=xc_q,
+                    v_max=v_max)
             counters["measured_chunks_read"] += w.n_chunks
             counters["measured_edge_read_bytes"] += w.nbytes
             counters["measured_chunks_device_decoded"] += w.n_device_chunks
         counters["edges_touched"] = edges_touched
 
         # Apply: read updated batches, masked update, write back + bitmap
-        upd_mask = has & vertex_valid
-        upd_batches = _batch_any(upd_mask, bs, b_cnt)
-        astate_pad = spill.read(upd_batches)                    # measured
-        astate = {k: v[:, :v_max] for k, v in astate_pad.items()}
-        state_j = {k: jnp.asarray(v) for k, v in astate.items()}
-        updates, new_active, ret = apply_fn(
-            state_j, jnp.asarray(agg), jnp.asarray(has), global_id)
-        spill.merge_write(astate_pad, updates, upd_mask,
-                          upd_batches)                          # measured
-        new_active = np.asarray(new_active, bool) & vertex_valid
-        spill.write_bitmap(new_active)                          # measured
-        total = float(np.where(upd_mask,
-                               np.asarray(ret, np.float32), 0.0).sum())
+        with span("ooc.apply") as sp:
+            upd_mask = has & vertex_valid
+            upd_batches = _batch_any(upd_mask, bs, b_cnt)
+            sp.set_metadata(batches=int(upd_batches.sum()))
+            astate_pad = spill.read(upd_batches)                # measured
+            astate = {k: v[:, :v_max] for k, v in astate_pad.items()}
+            state_j = {k: jnp.asarray(v) for k, v in astate.items()}
+            updates, new_active, ret = apply_fn(
+                state_j, jnp.asarray(agg), jnp.asarray(has), global_id)
+            spill.merge_write(astate_pad, updates, upd_mask,
+                              upd_batches)                      # measured
+            new_active = np.asarray(new_active, bool) & vertex_valid
+            spill.write_bitmap(new_active)                      # measured
+            total = float(np.where(upd_mask,
+                                   np.asarray(ret, np.float32), 0.0).sum())
 
         # Modeled vertex I/O (same formulas as _apply_and_account) next to
         # the measured bytes the spill actually served.

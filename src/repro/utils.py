@@ -7,9 +7,8 @@ import dataclasses
 import json
 import os
 import tempfile
-import time
 import zlib
-from typing import Any, Callable, Iterable
+from typing import Any, Iterable
 
 import jax
 import numpy as np
@@ -20,10 +19,17 @@ def enable_compile_cache() -> str:
     return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache
-    there and nothing is set here.  Otherwise the cache lives at the fixed
-    path ``<checkout>/.jax_cache`` (git-ignored): the directory is part of
-    what a later process must find again, so it never depends on a temp
-    name, a PID or the time."""
+    there and the directory is left alone.  Otherwise the cache lives at
+    the fixed path ``<checkout>/.jax_cache`` (git-ignored): the directory
+    is part of what a later process must find again, so it never depends
+    on a temp name, a PID or the time.
+
+    The cache key includes the programs' metadata (name stacks, source
+    lines).  JAX's default key strips it, so a program that differs from a
+    cached one only in its ``jax.named_scope`` phases would load the cached
+    executable, and a profile would show that executable's stale names in
+    place of the phases ``repro.core.tracing`` marks."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -129,30 +135,6 @@ def ceil_div(a: int, b: int) -> int:
 
 def round_up(a: int, b: int) -> int:
     return ceil_div(a, b) * b
-
-
-class Timer:
-    """Wall-clock timer; ``with Timer() as t: ...; t.seconds``."""
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self.t0
-        return False
-
-
-def time_fn(fn: Callable[[], Any], warmup: int = 1, iters: int = 3) -> float:
-    """Median wall-time of fn() in seconds, blocking on jax arrays."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn())
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts))
 
 
 @dataclasses.dataclass

@@ -116,6 +116,36 @@ def test_compile_cache_leaves_a_set_env_var_alone(tmp_path):
     assert _cache_dir_in_child(want) == [want, want]
 
 
+_SCOPE_CODE = (
+    "import sys; sys.path.insert(0, sys.argv[1])\n"
+    "import json, jax, jax.numpy as jnp\n"
+    "from repro.utils import enable_compile_cache\n"
+    "enable_compile_cache()\n"
+    "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+    "jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)\n"
+    "def f(x):\n"
+    "    with jax.named_scope(sys.argv[2]):\n"
+    "        return jnp.sin(x) * 2\n"
+    "text = jax.jit(f).lower(jnp.ones(8)).compile().as_text()\n"
+    "print(json.dumps(f'/{sys.argv[2]}/' in text))\n")
+
+
+def test_compile_cache_keeps_each_programs_name_stack(tmp_path):
+    """Two programs that differ only in a ``jax.named_scope`` each get
+    their own cache entry, so the second does not run (and profile) under
+    the first one's names."""
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("JAX_")}
+    env.update(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    for scope in ("first", "second"):
+        r = subprocess.run(
+            [sys.executable, "-c", _SCOPE_CODE, os.path.join(REPO, "src"),
+             scope], capture_output=True, text=True, timeout=120, env=env)
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert json.loads(r.stdout.strip().splitlines()[-1]) is True, scope
+    assert os.listdir(tmp_path)                 # the cache was written
+
+
 def test_compile_cache_default_is_one_path_in_the_checkout():
     first = _cache_dir_in_child(None)
     second = _cache_dir_in_child(None)

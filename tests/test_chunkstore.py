@@ -216,6 +216,26 @@ def test_vertex_spill_batch_io(tmp_path):
     assert bm.shape == (p_cnt, v_max) and bm.all()
 
 
+def test_vertex_spill_merge_write_leaves_the_read_arrays(tmp_path):
+    """The merge goes to new arrays: on the CPU a jnp.asarray of what
+    read() returned may alias it, and a computation still pending on that
+    alias must see the values that were read."""
+    p_cnt, b_cnt, bs, v_max = 2, 3, 4, 12   # v_pad == v_max: views alias
+    spill = VertexSpill(str(tmp_path), p_cnt, b_cnt, bs, v_max)
+    spill.load({"x": np.arange(p_cnt * v_max, dtype=np.float32)
+                .reshape(p_cnt, v_max)})
+    mask = np.ones((p_cnt, b_cnt), bool)
+    pad = spill.read(mask)
+    before = pad["x"].copy()
+    vm = np.zeros((p_cnt, v_max), bool)
+    vm[1, 3:9] = True
+    spill.merge_write(pad, {"x": np.full((p_cnt, v_max), -1.0, np.float32)},
+                      vm, mask)
+    np.testing.assert_array_equal(pad["x"], before)
+    np.testing.assert_array_equal(spill.state_views()["x"],
+                                  np.where(vm, -1.0, before[:, :v_max]))
+
+
 def test_vertex_spill_num_queries_validation(tmp_path):
     """A spill root records its Q; reopening with a different panel width
     must fail with a clear ChunkStoreError, not oblique key errors."""
