@@ -18,7 +18,8 @@ the public ``Engine`` / ``algorithms`` entry points: PageRank for a fixed
 2. OOC on a compressed chunk store with the default ``EngineConfig``, so
    chunk decode runs in the Pallas kernels; again with
    ``device_decode=False``, which must be bit-identical in values and every
-   counter but ``measured_chunks_device_decoded``.
+   counter but ``measured_chunks_device_decoded`` and
+   ``measured_device_decode_calls``.
 3. dist_ooc, W=4 in-process workers with ``parallel_workers=True`` on a
    sharded store: the same checks as phase 2.
 4. ``compute_backend="block_csr"`` on LOCAL and OOC at a smaller scale
@@ -73,6 +74,7 @@ COUNTER_RTOL = 1e-5     # float32 counter accumulation on LOCAL
 # The block-CSR backend's warning when a slot cannot be lowered to tiles.
 SLOT_FALLBACK = "compute_backend='block_csr' requires slot"
 DEVICE_DECODED = "measured_chunks_device_decoded"
+DEVICE_DECODE_CALLS = "measured_device_decode_calls"
 
 
 class CheckFailed(AssertionError):
@@ -184,7 +186,8 @@ def check_counters(name: str, got: Result, want: Result, keys) -> str:
 
 
 def check_identical(name: str, got: Result, want: Result,
-                    except_keys=(DEVICE_DECODED,)) -> str:
+                    except_keys=(DEVICE_DECODED, DEVICE_DECODE_CALLS)
+                    ) -> str:
     """Bit-identical values and every counter but ``except_keys``."""
     check(np.array_equal(got.pagerank.view(np.uint32),
                          want.pagerank.view(np.uint32)),

@@ -615,16 +615,19 @@ class ChunkStore:
             data = np.frombuffer(payload[vnb:], dtype="<f4").copy()
         return src, dst, data
 
-    def decode_chunk_device(self, q: int, p: int, k: int, rep: int,
-                            index: bytes, payload: bytes):
-        """Device-resident twin of :meth:`decode_chunk` (compressed stores
-        only): varint expansion, pair-delta cumsums, and the run-structure
-        restores run as Pallas kernels (:mod:`repro.kernels.varint`), and
-        only the final exact-length triple is synced back to host numpy —
-        bit-identical to the numpy decode.  Unlike the host path this is
-        one jit dispatch per stage rather than a GIL-holding numpy burst,
-        so the parallel executors call it *outside* the compute token
-        (DESIGN.md §8, §10)."""
+    def decode_batch_device(self, q: int, k: int, raw):
+        """Device-resident twin of :meth:`decode_chunk` for every chunk of
+        dst batch ``(q, k)`` at once (compressed stores only): ``raw`` is
+        ``[(p, rep, (index, payload, nbytes)), ...]`` as
+        :class:`ChunkPrefetcher` reads it.  Varint expansion, pair-delta
+        cumsums and the run-structure restores run as Pallas kernels
+        (:mod:`repro.kernels.varint`) over the batch's concatenated
+        sections, and only the exact-length result is synced back to host
+        numpy — bit-identical to the numpy decode, chunk by chunk.  Returns
+        the batch's concatenated ``(src_local, part, dst_local, data)``.
+        Unlike the host path this is one chain of jit dispatches rather
+        than a GIL-holding numpy burst, so the parallel executors call it
+        *outside* the compute token (DESIGN.md §8, §10)."""
         dec = self._device_decoder
         if dec is None:
             with self._lock:
@@ -632,7 +635,15 @@ class ChunkStore:
                 if dec is None:
                     dec = DeviceChunkDecoder(self)
                     self._device_decoder = dec
-        return dec.decode(q, p, k, rep, index, payload)
+        return dec.decode_batch(q, k, raw)
+
+    def decode_chunk_device(self, q: int, p: int, k: int, rep: int,
+                            index: bytes, payload: bytes):
+        """:meth:`decode_batch_device` of a batch of one chunk; returns
+        :meth:`decode_chunk`'s (src_local, dst_local, data) triple."""
+        src, _, dst, data = self.decode_batch_device(
+            q, k, [(p, rep, (index, payload, 0))])
+        return src, dst, data
 
     def read_chunk(self, q: int, p: int, k: int, rep: int):
         """Read + decode one chunk; returns (src_local, dst_local, data,
@@ -684,23 +695,62 @@ class ChunkStore:
 
 def _bucket(n: int) -> int:
     """Power-of-two padded width, at least 512 (the kernels' block): each
-    chunk pads to at most twice its own size, and each kernel compiles
-    for O(log) widths instead of one per chunk."""
+    batch pads to at most twice its own size, and each kernel compiles
+    for O(log) widths instead of one per batch."""
     return 1 << max(9, (max(int(n), 1) - 1).bit_length())
+
+
+def _staged(parts, dtype, width: int):
+    """``parts`` back to back in a zero-padded buffer ``width`` wide, or
+    of their :func:`_bucket` if wider; returns (buffer, live length)."""
+    n = sum(x.size for x in parts)
+    buf = np.zeros(max(width, _bucket(n)), dtype)
+    o = 0
+    for x in parts:
+        buf[o:o + x.size] = x
+        o += x.size
+    return buf, n
+
+
+def _slot_width(part_sizes) -> int:
+    """``vpad`` of :class:`DeviceChunkDecoder`: the power of two above
+    every local src, the stride of a batch's chunk slots."""
+    return 1 << (int(np.max(part_sizes)) - 1).bit_length()
+
+
+def device_decode_fits(part_sizes) -> bool:
+    """Whether a store of these partition sizes fits the device decode's
+    int32 domain: a batch's run heads reach ``num_partitions * vpad``."""
+    return len(part_sizes) * _slot_width(part_sizes) < 2**31
 
 
 class DeviceChunkDecoder:
     """Fused on-device chunk decode for one compressed store (DESIGN.md §10).
 
-    Each chunk's raw section bytes are staged into zero-padded buffers of
-    power-of-two widths (:func:`_bucket` of its own edge, run and byte
-    counts), the varint / delta / run-expand kernels of
-    :mod:`repro.kernels.varint` run on device, and only the exact-length
-    ``(src, dst, data)`` triple is synced back — bit-identical to
-    :meth:`ChunkStore.decode_chunk`.  Padding to a chunk's own bucket
-    rather than the store's largest chunk keeps the device work
-    proportional to the bytes read.  ``max_widths`` records the largest
-    padded widths this store can produce.
+    :meth:`decode_batch` decodes the chunks of one dst batch (one
+    :class:`ChunkPrefetcher` work item) in one chain of dispatches and one
+    sync.  On the host, plain copies stage the chunks' sections back to
+    back in zero-padded buffers: the dst-residue varints, the delta-varint
+    pairs, and the run heads a read gives directly (raw DCSR pairs, the
+    CSR rows of nonzero degree).  The varint / delta / run-expand kernels
+    of :mod:`repro.kernels.varint` then run once over the concatenations:
+    every run head carries ``slot * vpad + src`` at its position in the
+    batch, so one forward fill expands every chunk's src column and every
+    chunk starts a run of the one dst restore.  Only the ``[src, dst]``
+    pair comes back, in one transfer — bit-identical to
+    :meth:`ChunkStore.decode_chunk` chunk by chunk.
+
+    Every width follows one of two totals of the batch, each taken to
+    its power-of-two bucket (:func:`_bucket`) and at least that of the
+    store's largest chunk: ``E`` of its edges and ``R`` of its runs.  A
+    chunk has as many runs whichever section a read chooses (a CSR
+    chunk's rows of nonzero degree are its DCSR runs), so both groups of
+    heads are ``R`` wide, and the residue and pair streams at least
+    ``2 E`` and ``2 R`` bytes.  A batch's mix of CSR and DCSR chunks,
+    which the selective schedule changes from call to call, so makes no
+    shape the warm-up did not compile.  ``max_widths`` records the
+    largest padded widths a batch of this store can produce (all of its
+    chunks read).
     """
 
     def __init__(self, store: ChunkStore):
@@ -709,64 +759,119 @@ class DeviceChunkDecoder:
                 f"device decode requires a compressed store; the store at "
                 f"{store.root} was built with compression=False")
         # Imported here so opening a store never touches jax device state.
+        import jax
         from repro.kernels import varint as vk
         self._vk = vk
+        self._device_put = jax.device_put
+        self._zeros_by_width = {}
         self.store = store
         widths = dict(edges=1, runs=1, pair_bytes=1, residue_bytes=1)
         for q in store.partitions:
             lay = store._layout_of(q)
-            if lay.nnz.size:
+            if lay.nnz.size:         # totals of a batch with every chunk read
                 for k, arr in (("edges", lay.edges), ("runs", lay.nnz),
                                ("pair_bytes", lay.pair_nb),
                                ("residue_bytes", lay.dstv_nb)):
-                    widths[k] = max(widths[k], int(arr.max()))
+                    widths[k] = max(widths[k], int(arr.sum(axis=0).max()))
         self.max_widths = {k: _bucket(v) for k, v in widths.items()}
-        self._vpad = int(store.part_sizes.max()) + 1
+        # a batch is at least as wide as the store's largest chunk, so the
+        # many small batches of a sparse frontier share one set of widths
+        self._min_edges, self._min_runs = (_bucket(max(
+            (int(getattr(store._layout_of(q), f).max(initial=0))
+             for q in store.partitions), default=1))
+            for f in ("edges", "nnz"))
+        if not device_decode_fits(store.part_sizes):
+            raise ValueError(
+                f"device decode needs the int32 domain: num_partitions x "
+                f"the largest partition's size, rounded up to a power of "
+                f"two, < 2**31; the store at {store.root} has "
+                f"{store.num_partitions} partitions of up to "
+                f"{int(store.part_sizes.max())} vertices")
+        self._vpad = _slot_width(store.part_sizes)
 
-    def decode(self, q: int, p: int, k: int, rep: int,
-               index: bytes, payload: bytes):
+    def _zeros(self, n: int):
+        """A device-resident int32 zero vector of length ``n``, kept per
+        width: the group of heads a batch does not have."""
+        z = self._zeros_by_width.get(n)
+        if z is None:
+            z = self._zeros_by_width.setdefault(
+                n, self._device_put(np.zeros(n, np.int32)))
+        return z
+
+    def decode_batch(self, q: int, k: int, raw):
+        """Decode ``raw`` = ``[(p, rep, (index, payload, nbytes)), ...]``,
+        chunks of dst batch ``(q, k)``; returns the batch's concatenated
+        ``(src, part, dst, data)``."""
         vk = self._vk
         store = self.store
         lay = store._layout_of(q)
-        n_e = int(lay.edges[p, k])
-        nnz = int(lay.nnz[p, k])
-        v_src = int(store.part_sizes[p])
-        vnb = int(lay.dstv_nb[p, k])
-        base = k * store.batch_size
-        epad = _bucket(n_e)
-        if rep == REP_CSR:
-            idx = np.zeros(self._vpad, np.int32)
-            idx[:v_src + 1] = np.frombuffer(index, "<i4")
-            src_d, smask = vk.expand_csr_index(idx, v_src, n_e,
-                                               out_len=epad)
-        elif rep == REP_DCSR_DELTA:
-            pb = np.zeros(_bucket(len(index)), np.uint8)
-            pb[:len(index)] = np.frombuffer(index, np.uint8)
-            pv = vk.varint_decode(pb, len(index), count=2 * _bucket(nnz))
-            srcs, starts = vk.pair_delta_restore(pv)
-            src_d, smask = vk.expand_dcsr_index(srcs, starts, nnz, n_e,
-                                                out_len=epad)
-        elif rep == REP_DCSR:
-            pairs = np.frombuffer(index, PAIR_DT)
-            srcs = np.zeros(_bucket(nnz), np.int32)
-            starts = np.zeros(_bucket(nnz), np.int32)
-            srcs[:nnz] = pairs["src"]
-            starts[:nnz] = pairs["idx"]
-            src_d, smask = vk.expand_dcsr_index(srcs, starts, nnz, n_e,
-                                                out_len=epad)
-        else:
-            raise ValueError(f"unknown chunk representation {rep!r}")
-        db = np.zeros(_bucket(vnb), np.uint8)
-        db[:vnb] = np.frombuffer(payload[:vnb], np.uint8)
-        res = vk.varint_decode(db, vnb, count=epad)
-        dst_d = vk.dst_delta_restore(res, smask, base, n_e)
-        src = np.asarray(src_d)[:n_e]
-        dst = np.asarray(dst_d)[:n_e]
+        vpad = self._vpad
+        ps = np.array([p for p, _, _ in raw], np.int32)
+        n_e = lay.edges[ps, k].astype(np.int64)
+        eoff = np.cumsum(n_e) - n_e
+        n_total = int(n_e.sum())
+        epad = max(_bucket(n_total), self._min_edges)
+        # every chunk's runs, whichever section gives them: one head each
+        rpad = max(_bucket(int(lay.nnz[ps, k].sum())), self._min_runs)
+        residues, data = [], []
+        host_src, host_pos = [], []      # heads read directly
+        pair_bytes, seg = [], []         # delta pairs, decoded on device
+        runs = 0
+        for c, (p, rep, (index, payload, _)) in enumerate(raw):
+            vnb = int(lay.dstv_nb[p, k])
+            residues.append(np.frombuffer(payload, np.uint8, count=vnb))
+            if not store.values_elided:
+                data.append(np.frombuffer(payload, "<f4", offset=vnb))
+            slot, off = c * vpad, int(eoff[c])
+            if rep == REP_DCSR_DELTA:
+                seg.append((runs, slot, off))
+                runs += int(lay.nnz[p, k])
+                pair_bytes.append(np.frombuffer(index, np.uint8))
+            elif rep == REP_DCSR:
+                pairs = np.frombuffer(index, PAIR_DT)
+                host_src.append(pairs["src"] + slot)
+                host_pos.append(pairs["idx"] + off)
+            elif rep == REP_CSR:
+                idx = np.frombuffer(index, "<i4")
+                rows = np.flatnonzero(idx[1:] != idx[:-1])
+                host_src.append(rows + slot)
+                host_pos.append(idx[rows] + off)
+            else:
+                raise ValueError(f"unknown chunk representation {rep!r}")
+        # Every width follows epad or rpad alone (2 bytes an edge or a run
+        # hold its residues or its pairs), and both groups of heads always
+        # go, an absent one as a cached zero, staged ones as the zeros go
+        # (uncommitted device arrays): the mix of CSR and DCSR chunks,
+        # which the schedule changes from call to call, makes no new
+        # signature.
+        none = self._zeros(rpad)
+        host = delta = (none, none, 0)
+        if host_src:
+            hs, nh = _staged(host_src, np.int32, rpad)
+            hp, _ = _staged(host_pos, np.int32, rpad)
+            host = (*self._device_put((hs, hp)), nh)
+        if seg:
+            pb, npb = _staged(pair_bytes, np.uint8, 2 * rpad)
+            pv = vk.varint_decode(pb, npb, count=2 * rpad)
+            # per delta chunk: first run, slot, edge offset; one column per
+            # source partition at most, padded past every run so the
+            # segment search never lands on a pad
+            meta = np.full((3, store.num_partitions), 2**31 - 1, np.int32)
+            meta[:, :len(seg)] = np.array(seg, np.int32).T
+            delta = (*vk.pair_delta_restore(pv, *meta), runs)
+        srcs, starts, live = zip(host, delta)
+        src_d, smask = vk.expand_dcsr_index(srcs, starts, live, n_total,
+                                            vpad, out_len=epad)
+        rb, nrb = _staged(residues, np.uint8, 2 * epad)
+        vals = vk.varint_decode(rb, nrb, count=epad)
+        out = np.asarray(vk.dst_delta_restore(
+            vals, smask, k * store.batch_size, n_total, src_d))
+        part = np.repeat(ps, n_e)
         if store.values_elided:
-            data = np.ones(n_e, np.float32)
+            weights = np.ones(n_total, np.float32)
         else:
-            data = np.frombuffer(payload[vnb:], dtype="<f4").copy()
-        return src, dst, data
+            weights = np.concatenate(data)
+        return out[0, :n_total], part, out[1, :n_total], weights
 
 
 class ShardedChunkStore:
@@ -1297,9 +1402,8 @@ class DiskChunkSource:
                      index: bytes, payload: bytes):
         return self.store.decode_chunk(q, p, k, rep, index, payload)
 
-    def decode_chunk_device(self, q: int, p: int, k: int, rep: int,
-                            index: bytes, payload: bytes):
-        return self.store.decode_chunk_device(q, p, k, rep, index, payload)
+    def decode_batch_device(self, q: int, k: int, raw):
+        return self.store.decode_batch_device(q, k, raw)
 
 
 # ---------------------------------------------------------------------------
@@ -1331,6 +1435,7 @@ class BatchWork:
     nbytes: int            # measured bytes read for this item
     n_chunks: int
     n_device_chunks: int = 0   # chunks decoded on device (DESIGN.md §10)
+    n_device_calls: int = 0    # device decode dispatch chains (one a batch)
 
 
 class ChunkPrefetcher:
@@ -1369,16 +1474,17 @@ class ChunkPrefetcher:
     one per pipeline, which the parallel dist_ooc executor would
     otherwise do 2·W times per iteration.
 
-    ``device_decode`` routes the decode of each chunk through the Pallas
-    kernel pipeline (:meth:`ChunkStore.decode_chunk_device`, DESIGN.md
-    §10) instead of the host numpy codec.  The device decode is NOT run
-    under the compute token: it is a chain of jit dispatches that release
-    the GIL while the accelerator works, not a host-CPU burst, so holding
-    the token would serialize exactly the work that no longer needs
-    serializing.  Results are bit-identical either way; the number of
-    device-decoded chunks is reported per item
-    (``BatchWork.n_device_chunks`` -> the executors'
-    ``measured_chunks_device_decoded`` counter).
+    ``device_decode`` routes the decode of each item through the Pallas
+    kernel pipeline (:meth:`ChunkStore.decode_batch_device`, DESIGN.md
+    §10) instead of the host numpy codec: all of the item's chunks in one
+    chain of jit dispatches and one sync.  The device decode is NOT run
+    under the compute token: it releases the GIL while the accelerator
+    works, not a host-CPU burst, so holding the token would serialize
+    exactly the work that no longer needs serializing.  Results are
+    bit-identical either way; each item reports its device-decoded chunks
+    and decode calls (``BatchWork.n_device_chunks`` / ``n_device_calls``
+    -> the executors' ``measured_chunks_device_decoded`` /
+    ``measured_device_decode_calls`` counters).
     """
 
     _DONE = object()
@@ -1400,10 +1506,9 @@ class ChunkPrefetcher:
             self._join = lambda: future.exception()
 
     @staticmethod
-    def _assemble(q: int, k: int, decoded, n_chunks: int,
-                  n_device: int = 0) -> "BatchWork":
-        """Concatenate per-chunk (src, dst, data) triples into one
-        :class:`BatchWork` (shared by the host and device decode paths)."""
+    def _assemble(q: int, k: int, decoded, n_chunks: int) -> "BatchWork":
+        """Concatenate the host codec's per-chunk (src, dst, data) triples
+        into one :class:`BatchWork`."""
         srcs, parts, dsts, datas = [], [], [], []
         nbytes = 0
         for p, (s, d, w), nb in decoded:
@@ -1417,7 +1522,7 @@ class ChunkPrefetcher:
         return BatchWork(
             q=q, k=k, src=cat(srcs, np.int32), part=cat(parts, np.int32),
             dst=cat(dsts, np.int32), data=cat(datas, np.float32),
-            nbytes=nbytes, n_chunks=n_chunks, n_device_chunks=n_device)
+            nbytes=nbytes, n_chunks=n_chunks)
 
     def _put(self, item) -> bool:
         """Blocking put that aborts when the consumer closed the pipeline
@@ -1448,19 +1553,19 @@ class ChunkPrefetcher:
                         raw = [(p, rep,
                                 self._source.read_chunk_bytes(q, p, k, rep))
                                for p, rep in chunks]
-                        sp.set_metadata(
-                            bytes=sum(nb for _, _, (_, _, nb) in raw))
+                        nbytes = sum(nb for _, _, (_, _, nb) in raw)
+                        sp.set_metadata(bytes=nbytes)
+                    dev = int(self._device_decode)
                     with span("chunk.decode", q=q, k=k, chunks=n,
-                              device=int(self._device_decode)) as sp:
-                        if self._device_decode:
-                            # Device decode: jit dispatches, GIL released
-                            # while the kernels run — no compute token.
-                            decoded = [
-                                (p, self._source.decode_chunk_device(
-                                    q, p, k, rep, index, payload), nb)
-                                for p, rep, (index, payload, nb) in raw]
-                            work = self._assemble(q, k, decoded, n,
-                                                  n_device=n)
+                              device=dev, calls=dev) as sp:
+                        if dev:
+                            # Device decode: one dispatch chain, GIL
+                            # released while the kernels run — no token.
+                            work = BatchWork(
+                                q, k, *self._source.decode_batch_device(
+                                    q, k, raw),
+                                nbytes=nbytes, n_chunks=n,
+                                n_device_chunks=n, n_device_calls=1)
                         else:
                             with self._lock_ctx:  # token held: decode burst
                                 decoded = [

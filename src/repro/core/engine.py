@@ -59,7 +59,7 @@ from repro.core import executor as _executor
 from repro.core import multiquery as _multiquery
 from repro.core.chunkstore import (
     ChunkStore, DiskChunkSource, HBMChunkSource, ShardedChunkStore,
-    VertexSpill,
+    VertexSpill, device_decode_fits,
 )
 from repro.core.exchange import WIRE_MSG_BYTES
 from repro.core.formats import ChunkFormats, build_block_tiles
@@ -202,7 +202,9 @@ class EngineConfig:
     kernels would compile rather than run interpreted (i.e. a real
     accelerator backend is present, same auto-selection as
     ``kernels/csr_spmv.py``); uncompressed stores always decode on the
-    host (their payload is a plain memcpy, nothing to decode)."""
+    host (their payload is a plain memcpy, nothing to decode), and so do
+    stores whose partitions overflow the kernels' int32 domain
+    (``chunkstore.device_decode_fits``; ``True`` raises for them)."""
 
     physical_sparse_exchange: bool | None = None
     """SHARD_MAP only: realize the adaptive wire physically (DESIGN.md
@@ -260,9 +262,10 @@ MEASURED_KEYS = (
     "measured_chunks_read", "measured_edge_read_bytes",
     "measured_vertex_read_bytes", "measured_vertex_write_bytes",
     # how many of the measured chunk reads were decoded by the Pallas
-    # kernels (EngineConfig.device_decode); no analytic twin — it reports
-    # the decode path taken, not bytes moved
-    "measured_chunks_device_decoded",
+    # kernels (EngineConfig.device_decode), and in how many decode calls
+    # (one per dst batch read); no analytic twin — they report the decode
+    # path taken, not bytes moved
+    "measured_chunks_device_decoded", "measured_device_decode_calls",
 )
 
 MEASURED_PAIRS = (
@@ -390,11 +393,19 @@ class Engine:
                 "device_decode=True requires compression=True: uncompressed "
                 "chunk payloads are plain column memcpys with nothing to "
                 "decode on device")
+        fits = device_decode_fits(spec.partition_sizes())
+        if config.device_decode and (self._ooc or self._dist_ooc) \
+                and not fits:
+            raise ValueError(
+                "device_decode=True needs num_partitions x the largest "
+                "partition's size, rounded up to a power of two, below "
+                "2**31 (the kernels' int32 domain); use more, smaller "
+                "partitions or the host decode")
         if config.device_decode is None:
             from repro.kernels.csr_spmv import default_interpret
             self.device_decode = (config.compression
                                   and (self._ooc or self._dist_ooc)
-                                  and not default_interpret())
+                                  and fits and not default_interpret())
         else:
             self.device_decode = bool(config.device_decode)
         # Resolve the physical_sparse_exchange knob (docstring on

@@ -943,6 +943,7 @@ def make_ooc_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
             counters["measured_chunks_read"] += w.n_chunks
             counters["measured_edge_read_bytes"] += w.nbytes
             counters["measured_chunks_device_decoded"] += w.n_device_chunks
+            counters["measured_device_decode_calls"] += w.n_device_calls
         counters["edges_touched"] = edges_touched
 
         # Apply: read updated batches, masked update, write back + bitmap
@@ -1309,6 +1310,7 @@ def make_dist_ooc_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
 
             w_edges = 0.0
             w_dev_chunks = 0.0
+            w_dev_calls = 0.0
             cur = None
             xv_q = xc_q = None
             for item in ChunkPrefetcher(source, lazy_schedule(),
@@ -1323,6 +1325,7 @@ def make_dist_ooc_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
                         cw[ck] = cw.get(ck, 0.0) + cv
                     continue
                 w_dev_chunks += item.n_device_chunks
+                w_dev_calls += item.n_device_calls
                 with tok:                   # compute token: combine burst
                     if backend == "block_csr" and xv_q is None:
                         xv_q, xc_q = _block_dest_vectors(
@@ -1362,6 +1365,7 @@ def make_dist_ooc_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
             cw["measured_chunks_read"] = source.store.chunks_read - cr0
             cw["measured_edge_read_bytes"] = edge_b
             cw["measured_chunks_device_decoded"] = w_dev_chunks
+            cw["measured_device_decode_calls"] = w_dev_calls
             cw["measured_vertex_read_bytes"] = spill.bytes_read - sr0
             cw["measured_vertex_write_bytes"] = spill.bytes_written - sw0
             cw["edges_touched"] = w_edges

@@ -625,6 +625,7 @@ def make_ooc_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
             counters["measured_chunks_read"] += w.n_chunks
             counters["measured_edge_read_bytes"] += w.nbytes
             counters["measured_chunks_device_decoded"] += w.n_device_chunks
+            counters["measured_device_decode_calls"] += w.n_device_calls
         counters["edges_touched"] = edges_touched
 
         # Apply per alive query into its own columns + bitmap.
@@ -859,6 +860,7 @@ def make_dist_ooc_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn,
 
             w_edges = 0.0
             w_dev_chunks = 0.0
+            w_dev_calls = 0.0
             cur = None
             xv_p = xc_p = None
             for item in ChunkPrefetcher(source, lazy_schedule(),
@@ -873,6 +875,7 @@ def make_dist_ooc_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn,
                         cw[ck] = cw.get(ck, 0.0) + cv
                     continue
                 w_dev_chunks += item.n_device_chunks
+                w_dev_calls += item.n_device_calls
                 with tok:                   # compute token: combine burst
                     if backend == "segment":
                         for j in alive:
@@ -942,6 +945,7 @@ def make_dist_ooc_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn,
             cw["measured_chunks_read"] = source.store.chunks_read - cr0
             cw["measured_edge_read_bytes"] = edge_b
             cw["measured_chunks_device_decoded"] = w_dev_chunks
+            cw["measured_device_decode_calls"] = w_dev_calls
             cw["measured_vertex_read_bytes"] = spill.bytes_read - sr0
             cw["measured_vertex_write_bytes"] = spill.bytes_written - sw0
             cw["edges_touched"] = w_edges

@@ -4,10 +4,10 @@ tier (DESIGN.md §9, §10).
 The numpy codec in :mod:`repro.core.codec` decodes a compressed chunk with
 three host-CPU bursts: LEB128 varint expansion, interleaved pair-delta
 cumsums, and the per-run dst-residue restore.  These kernels move that
-byte-level work onto the accelerator so a prefetched chunk goes bytes ->
-device buffer -> decode -> combine without a host round-trip — and without
-the compute token: the decode becomes one jit dispatch instead of a
-GIL-holding numpy burst (DESIGN.md §8).
+byte-level work onto the accelerator so the prefetched chunks of one dst
+batch go bytes -> device buffers -> decode -> host in one chain of jit
+dispatches and one sync — and without the compute token: the decode is
+a few dispatches instead of a GIL-holding numpy burst (DESIGN.md §8).
 
 Scope: the **int32 value domain** (values < 2**31, <= 5 varint groups) —
 the same domain :func:`repro.core.codec.varint_sizes`'s jnp path prices,
@@ -214,80 +214,100 @@ def varint_decode(buf: jnp.ndarray, nbytes, *, count: int,
 # ---------------------------------------------------------------------------
 # Delta restores (device twins of the codec's cumsum/repeat restores)
 # ---------------------------------------------------------------------------
+#
+# The restores decode every chunk of one dst batch in one chain of
+# dispatches (DeviceChunkDecoder.decode_batch): the chunks' streams are
+# concatenated, each chunk's cumsums restart at its first pair, and every
+# run head carries ``slot * vpad + src`` (slot = the chunk's place in the
+# batch, src < vpad), so head values increase across chunk boundaries and
+# one running-max forward fill serves the whole batch.  One chunk is a
+# batch of one.  vpad is a power of two, so a mask strips the slot: TPUs
+# have no integer divide, and XLA's emulation of ``%`` by a runtime value
+# compiles for tens of seconds at 2**20 lanes.
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pair_delta_restore(deltas: jnp.ndarray, *,
+def pair_delta_restore(deltas: jnp.ndarray, seg_runs, seg_src, seg_pos, *,
                        interpret: bool | None = None):
     """Interleaved [ds0, di0, ds1, di1, ...] int32 deltas -> (src, idx)
     int32 cumulative arrays — the device twin of
-    codec.pair_delta_restore.  Zero-padded tails stay at the final value
-    (cumsum of zeros), which downstream consumers mask by ``nnz``."""
+    codec.pair_delta_restore, for several chunks' pair streams back to
+    back.  Chunk c's pairs start at run ``seg_runs[c]`` (sorted; pad with
+    a value past the last run); its cumsums restart there, and
+    ``seg_src[c]`` / ``seg_pos[c]`` are added to its src / idx.  Both
+    running sums may wrap int32 across chunks; a chunk's difference of two
+    wrapped sums is still exact.  Zero-padded tails stay at the final
+    value, which downstream consumers mask by the live run count."""
     if interpret is None:
         interpret = default_interpret()
     v = deltas.reshape(-1, 2)
     src = blocked_scan(v[:, 0], mode="add", interpret=interpret)
     idx = blocked_scan(v[:, 1], mode="add", interpret=interpret)
+    r = jnp.arange(src.shape[0], dtype=jnp.int32)
+    c = jnp.maximum(
+        jnp.searchsorted(seg_runs, r, side="right").astype(jnp.int32) - 1, 0)
+    first = jnp.minimum(seg_runs[c], src.shape[0] - 1)   # pads: no chunk
+    src = src - (src - v[:, 0])[first] + seg_src[c]
+    idx = idx - (idx - v[:, 1])[first] + seg_pos[c]
     return src, idx
 
 
 @functools.partial(jax.jit, static_argnames=("out_len", "interpret"))
-def expand_dcsr_index(srcs: jnp.ndarray, starts: jnp.ndarray, nnz,
-                      n_e, *, out_len: int,
-                      interpret: bool | None = None):
+def expand_dcsr_index(srcs: tuple, starts: tuple, nnz: tuple, n_e, vpad, *,
+                      out_len: int, interpret: bool | None = None):
     """DCSR (src, start) runs -> per-edge (src [out_len], run-start mask
     [out_len]) via scatter + running-max forward fill.
 
-    srcs is strictly increasing over the first ``nnz`` entries and
-    starts[0] == 0 for nonempty chunks, so a max-scan of the scattered
-    run heads reconstructs numpy's ``repeat(srcs, runs)`` exactly."""
+    ``srcs`` / ``starts`` / ``nnz`` are tuples, groups of heads of one
+    width W (the first ``nnz[g]`` of group g live, at most W in all), each
+    head holding ``slot * vpad + src`` at its edge position in the batch
+    (``vpad`` a power of two above every src).  Head values increase over
+    the live heads taken group after group, and the first sits at
+    position 0, so a max-scan of the scattered heads reconstructs numpy's
+    ``repeat(srcs, runs)`` exactly; the returned src is the fill mod
+    ``vpad``.  The groups' live heads are first shifted into one list, so
+    one scatter serves them all."""
     if interpret is None:
         interpret = default_interpret()
-    m = jnp.arange(srcs.shape[0], dtype=jnp.int32)
-    ok = m < nnz
-    tgt = jnp.where(ok, starts, out_len)
-    src0 = jnp.zeros((out_len,), jnp.int32).at[tgt].max(
-        jnp.where(ok, srcs, 0), mode="drop")
-    smask = jnp.zeros((out_len,), jnp.int32).at[tgt].set(1, mode="drop")
-    src = blocked_scan(src0, mode="max", interpret=interpret)
-    keep = jnp.arange(out_len, dtype=jnp.int32) < n_e
-    return jnp.where(keep, src, 0), jnp.where(keep, smask, 0)
+    width = srcs[0].shape[0]
+    lane = jnp.arange(width, dtype=jnp.int32)
+    pad = jnp.zeros((width,), jnp.int32)
 
-
-@functools.partial(jax.jit, static_argnames=("out_len", "interpret"))
-def expand_csr_index(idx: jnp.ndarray, v_src, n_e, *, out_len: int,
-                     interpret: bool | None = None):
-    """CSR idx [Vpad + 1] -> per-edge (src [out_len], run-start mask
-    [out_len]).  Rows >= v_src are ignored; rows with zero degree place no
-    run head.  Same scatter + max-fill shape as :func:`expand_dcsr_index`
-    (row ids are increasing and the first live row starts at offset 0)."""
-    if interpret is None:
-        interpret = default_interpret()
-    vpad = idx.shape[0] - 1
-    r = jnp.arange(vpad, dtype=jnp.int32)
-    deg = idx[1:] - idx[:-1]
-    ok = (r < v_src) & (deg > 0)
-    tgt = jnp.where(ok, idx[:-1], out_len)
+    def after(x, at):                # x moved to start at lane ``at``
+        return jax.lax.dynamic_slice(jnp.concatenate([pad, x]),
+                                     (width - at,), (width,))
+    heads, pos, live = pad, pad, 0
+    for s, st, n in zip(srcs, starts, nnz):
+        tail = lane >= live
+        heads = jnp.where(tail, after(s, live), heads)
+        pos = jnp.where(tail, after(st, live), pos)
+        live = live + n
+    # one scatter: heads land as src + 1, so a nonzero lane is a run start
+    ok = lane < live
+    tgt = jnp.where(ok, pos, out_len)
     src0 = jnp.zeros((out_len,), jnp.int32).at[tgt].max(
-        jnp.where(ok, r, 0), mode="drop")
-    smask = jnp.zeros((out_len,), jnp.int32).at[tgt].set(1, mode="drop")
-    src = blocked_scan(src0, mode="max", interpret=interpret)
+        jnp.where(ok, heads + 1, 0), mode="drop")
+    src = (blocked_scan(src0, mode="max", interpret=interpret) - 1) \
+        & (vpad - 1)
     keep = jnp.arange(out_len, dtype=jnp.int32) < n_e
-    return jnp.where(keep, src, 0), jnp.where(keep, smask, 0)
+    return jnp.where(keep, src, 0), (keep & (src0 > 0)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dst_delta_restore(res: jnp.ndarray, start_mask: jnp.ndarray, base,
-                      n_e, *, interpret: bool | None = None):
-    """Residue stream + run-start mask -> dst int32 — the device twin of
-    codec.dst_delta_restore.
+                      n_e, src, *, interpret: bool | None = None):
+    """Residue stream + run-start mask -> ``[src, dst]`` int32 [2, N] —
+    the device twin of codec.dst_delta_restore, returning the per-edge
+    ``src`` beside dst so the batch comes back to the host in one
+    transfer.
 
     csum[j] - csum[start_of_run(j) - 1] telescopes the in-run deltas.  The
     run head of every edge is found by forward-filling head *positions*
     with a max-scan, and the "residues before" value is gathered from
-    there.  The running sum of a large chunk passes 2**31 (every run
+    there.  The running sum of a large batch passes 2**31 (every run
     restarts at its batch offset), so csum may wrap; positions never do,
     and the in-run difference of two wrapped int32 sums is still exact.
-    Entries beyond ``n_e`` are zeroed."""
+    A batch of chunks needs nothing more: every chunk starts a run, and
+    they share the base.  Entries beyond ``n_e`` are zeroed."""
     if interpret is None:
         interpret = default_interpret()
     pos = jnp.arange(res.shape[0], dtype=jnp.int32)
@@ -295,4 +315,4 @@ def dst_delta_restore(res: jnp.ndarray, start_mask: jnp.ndarray, base,
     head = blocked_scan(jnp.where(start_mask > 0, pos, 0), mode="max",
                         interpret=interpret)
     before = (csum - res)[head]
-    return jnp.where(pos < n_e, base + csum - before, 0)
+    return jnp.stack([src, jnp.where(pos < n_e, base + csum - before, 0)])

@@ -16,13 +16,16 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import csr_spmv as cs
 from repro.kernels import varint as vk
 
-# chip_smoke.py on one chip (seed 0, RMAT scale 21, P=8): the largest
-# padded widths of its OOC store's device decode (DeviceChunkDecoder
-# .max_widths, power-of-two buckets) ...
-DECODE_EDGES = 1_048_576        # edges per chunk
-DECODE_PAIR_BYTES = 262_144     # DCSR-delta pair-stream bytes per chunk
-DECODE_RESIDUE_BYTES = 2_097_152  # dst-residue bytes per chunk
-DECODE_RUNS = 131_072           # DCSR runs (sources) per chunk
+# chip_smoke.py on one chip (seed 0, RMAT scale 21, P=8): the padded
+# widths of its OOC store's device decode, which decodes a dst batch's
+# chunks together (DeviceChunkDecoder.decode_batch) in widths set by the
+# batch's edge and run buckets E and R.  Its largest chunk pads to 2**20
+# edges and 2**17 runs; a batch holds at most one chunk per source
+# partition, so E and R are at most P = 8 times those.  The residue and
+# pair streams are at least 2 E and 2 R bytes wide ...
+DECODE_EDGES = 8_388_608        # E, edges per batch
+DECODE_RUNS = 1_048_576         # R, runs (heads) per batch
+DECODE_CHUNKS = 8               # chunks per batch, at most
 # ... and its block_csr phase's LOCAL tiles (RMAT scale 14, P=8), per
 # destination partition: tile slots, kernel grid rows x tiles per row,
 # and source column blocks.
@@ -107,12 +110,35 @@ def test_blocked_scan_compiles(one_chip, mode):
              ((DECODE_EDGES,), jnp.int32))
 
 
-@pytest.mark.parametrize("nbytes,count", [
-    (DECODE_RESIDUE_BYTES, DECODE_EDGES),
-    (DECODE_PAIR_BYTES, 2 * DECODE_RUNS),
-], ids=["residues", "pairs"])
-def test_varint_decode_compiles(one_chip, nbytes, count):
+@pytest.mark.parametrize("count", [DECODE_EDGES, 2 * DECODE_RUNS],
+                         ids=["residues", "pairs"])
+def test_varint_decode_compiles(one_chip, count):
     _compile(one_chip,
              lambda b, n: vk.varint_decode(b, n, count=count,
                                            interpret=False),
-             ((nbytes,), jnp.uint8), ((), jnp.int32))
+             ((2 * count,), jnp.uint8), ((), jnp.int32))
+
+
+def test_pair_delta_restore_compiles(one_chip):
+    seg = ((DECODE_CHUNKS,), jnp.int32)
+    _compile(one_chip,
+             lambda d, r, s, p: vk.pair_delta_restore(d, r, s, p,
+                                                      interpret=False),
+             ((2 * DECODE_RUNS,), jnp.int32), seg, seg, seg)
+
+
+def test_expand_dcsr_index_compiles(one_chip):
+    def fn(hs, hp, ds, dp, nh, nd, n_e, vpad):
+        return vk.expand_dcsr_index((hs, ds), (hp, dp), (nh, nd), n_e, vpad,
+                                    out_len=DECODE_EDGES, interpret=False)
+    heads, scalar = ((DECODE_RUNS,), jnp.int32), ((), jnp.int32)
+    _compile(one_chip, fn, heads, heads, heads, heads, scalar, scalar,
+             scalar, scalar)
+
+
+def test_dst_delta_restore_compiles(one_chip):
+    edges, scalar = ((DECODE_EDGES,), jnp.int32), ((), jnp.int32)
+    _compile(one_chip,
+             lambda r, m, b, n, s: vk.dst_delta_restore(r, m, b, n, s,
+                                                        interpret=False),
+             edges, edges, scalar, scalar, edges)

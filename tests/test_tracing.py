@@ -36,6 +36,23 @@ def graph():
     return dg, build_formats(dg)
 
 
+def _host_spans(root):
+    """The ``dfo.*`` spans of the trace under ``root``: ``(name, args)``
+    per host line."""
+    path, = glob.glob(os.path.join(root, "trace", "**", "*.xplane.pb"),
+                      recursive=True)
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            found = [(ev.name[len(PREFIX):], dict(ev.stats))
+                     for ev in line.events if ev.name.startswith(PREFIX)]
+            if found:
+                lines.append(found)
+    return lines
+
+
 @pytest.fixture(scope="module")
 def traced(graph, tmp_path_factory):
     """``(spans by line, run counters)`` of one traced BFS and PageRank on
@@ -52,17 +69,7 @@ def traced(graph, tmp_path_factory):
         _, pr = algorithms.pagerank(eng, 2)
     finally:
         jax.profiler.stop_trace()
-    path, = glob.glob(os.path.join(root, "trace", "**", "*.xplane.pb"),
-                      recursive=True)
-    lines = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            found = [(ev.name[len(PREFIX):], dict(ev.stats))
-                     for ev in line.events if ev.name.startswith(PREFIX)]
-            if found:
-                lines.append(found)
+    lines = _host_spans(root)
     counters = collections.Counter()
     for stats in (bfs, pr):
         counters.update({k: float(v) for k, v in stats.counters.items()})
@@ -107,6 +114,8 @@ def test_span_counts_agree_with_the_io_audit(traced):
         counters["measured_chunks_read"]
     assert total["ooc.dispatch", "chunks"] == counters["chunks_read"]
     assert total["chunk.decode", "edges"] == total["ooc.combine", "edges"]
+    assert total["chunk.decode", "calls"] == \
+        counters["measured_device_decode_calls"]
     # spill spans carry the spill's bytes, ProcessVertices' included; the
     # first call of each of the two jobs loads the job's initial state,
     # which writes the active bitmap outside the audit
@@ -114,6 +123,27 @@ def test_span_counts_agree_with_the_io_audit(traced):
         counters["measured_vertex_read_bytes"]
     assert total["spill.write", "bytes"] == \
         counters["measured_vertex_write_bytes"] + 2 * eng.spill.bitmap_nbytes()
+
+
+def test_device_decode_spans_count_the_calls(tmp_path):
+    # one decode call per streamed batch, each marked on its chunk.decode
+    g = rmat_graph(8, 8, seed=3, weighted=False)
+    dg = build_dist_graph(g, make_spec(g, num_partitions=4))
+    fm = build_formats(dg)
+    eng = Engine(dg, fm, EngineConfig(executor="ooc", device_decode=True),
+                 store=ChunkStore.build(dg, fm, str(tmp_path / "store")))
+    algorithms.pagerank(eng, 1)              # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        _, stats = algorithms.pagerank(eng, 2)
+    finally:
+        jax.profiler.stop_trace()
+    decodes = [args for line in _host_spans(tmp_path)
+               for name, args in line if name == "chunk.decode"]
+    assert decodes and all(a["calls"] == 1 == a["device"] for a in decodes)
+    assert stats.counters["measured_device_decode_calls"] == len(decodes)
+    assert stats.counters["measured_chunks_device_decoded"] == \
+        sum(a["chunks"] for a in decodes)
 
 
 def test_local_step_carries_the_phase_scopes(graph):

@@ -9,6 +9,7 @@ parity case runs too.
 
 Run standalone by ``scripts/ci.sh`` as the device-decode parity gate.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -21,14 +22,20 @@ from repro.core import (
     codec, make_spec,
 )
 from repro.core import algorithms as alg
-from repro.core.chunkstore import REP_CSR, REP_DCSR, REP_DCSR_DELTA
-from repro.data.graphs import rmat_graph
+from repro.core import chunkstore
+from repro.core.chunkstore import (
+    REP_CSR, REP_DCSR, REP_DCSR_DELTA, ChunkPrefetcher,
+)
+from repro.data.graphs import GraphData, rmat_graph
 from repro.kernels import varint as vk
 from repro.kernels.csr_spmv import default_interpret
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 INT32_MAX = 2**31 - 1
+# the counters that report which decode path ran, not what it computed
+DEVICE_DECODE_KEYS = ("measured_chunks_device_decoded",
+                      "measured_device_decode_calls")
 
 
 def _kernel_decode(vals, *, interpret=None):
@@ -105,83 +112,145 @@ if HAVE_HYPOTHESIS:
             _kernel_decode(vals),
             np.asarray(vals, np.int64).astype(np.int32))
 
+    VPAD = 2**24                 # slot stride: a power of two above srcs
+
+    def _pow2(n):
+        return 1 << (max(int(n), 1) - 1).bit_length()
+
     @st.composite
-    def chunks(draw):
-        """An adversarial sorted chunk: edges grouped into runs by src,
-        dst non-decreasing within a run, all >= the batch base."""
+    def batches(draw):
+        """1-3 adversarial sorted chunks of one dst batch: edges grouped
+        into runs by src, dst non-decreasing within a run, all >= the
+        shared batch base.  Each chunk's run heads reach the expand either
+        as delta-varint pairs (REP_DCSR_DELTA) or read directly."""
         base = draw(st.integers(0, 2**20)) * 16
-        n_runs = draw(st.integers(0, 12))
-        srcs = draw(st.lists(st.integers(0, 2**24), min_size=n_runs,
-                             max_size=n_runs, unique=True))
-        srcs = np.sort(np.asarray(srcs, np.int64))
-        runs, dst = [], []
-        for _ in range(n_runs):
-            r = draw(st.integers(1, 9))
-            runs.append(r)
-            d = draw(st.lists(st.integers(0, 2**20), min_size=r, max_size=r))
-            dst.extend(base + np.sort(np.asarray(d, np.int64)))
-        return base, srcs, np.asarray(runs, np.int64), \
-            np.asarray(dst, np.int64)
+        out = []
+        for _ in range(draw(st.integers(1, 3))):
+            n_runs = draw(st.integers(0, 12))
+            srcs = draw(st.lists(st.integers(0, VPAD - 1), min_size=n_runs,
+                                 max_size=n_runs, unique=True))
+            runs, dst = [], [np.zeros(0, np.int64)]
+            for _ in range(n_runs):
+                r = draw(st.integers(1, 9))
+                runs.append(r)
+                d = draw(st.lists(st.integers(0, 2**20), min_size=r,
+                                  max_size=r))
+                dst.append(base + np.sort(np.asarray(d, np.int64)))
+            out.append((np.sort(np.asarray(srcs, np.int64)),
+                        np.asarray(runs, np.int64), np.concatenate(dst),
+                        draw(st.booleans())))
+        return base, out
 
     @settings(max_examples=25, deadline=None)
-    @given(chunks())
-    def test_chunk_restore_kernels_match_codec(chunk):
-        base, srcs, runs, dst = chunk
-        nnz, n_e = srcs.size, dst.size
-        starts = (np.cumsum(runs) - runs).astype(np.int64)
-        out_len = max(n_e, 1)
-        # pair stream: kernel decode + kernel cumsum restore
-        pv = codec.pair_delta_values(srcs, starts)
-        dec = _kernel_decode(pv)
-        pad = np.zeros(2 * max(nnz, 1), np.int32)
-        pad[:dec.size] = dec
-        s2, i2 = vk.pair_delta_restore(pad)
-        np.testing.assert_array_equal(np.asarray(s2)[:nnz], srcs)
-        np.testing.assert_array_equal(np.asarray(i2)[:nnz], starts)
+    @given(batches())
+    def test_chunk_restore_kernels_match_codec(batch):
+        # stages a batch as DeviceChunkDecoder.decode_batch does, and checks
+        # every stage against the codec's restores concatenated
+        base, chunks = batch
+        n_es = np.array([d.size for _, _, d, _ in chunks])
+        eoff = np.cumsum(n_es) - n_es
+        n_e = int(n_es.sum())
+        out_len = _pow2(n_e)
+        width = _pow2(sum(s.size for s, _, _, _ in chunks))
+        direct_src, direct_pos, pair_vals, seg, residues = [], [], [], [], []
+        delta_src, delta_pos = [], []
+        runs_seen = 0
+        for c, (srcs, runs, dst, as_pairs) in enumerate(chunks):
+            starts = np.cumsum(runs) - runs
+            residues.append(codec.dst_delta_values(dst, starts, base))
+            if as_pairs:
+                seg.append((runs_seen, c * VPAD, eoff[c]))
+                runs_seen += srcs.size
+                pair_vals.append(codec.pair_delta_values(srcs, starts))
+                delta_src.append(srcs + c * VPAD)
+                delta_pos.append(starts + eoff[c])
+            else:
+                direct_src.append(srcs + c * VPAD)
+                direct_pos.append(starts + eoff[c])
+
+        def staged(parts):
+            buf = np.zeros(width, np.int32)
+            x = np.concatenate([np.zeros(0, np.int64)] + parts)
+            buf[:x.size] = x
+            return buf, x.size
+        none = np.zeros(width, np.int32)
+        delta = (none, none, 0)
+        if seg:
+            # pair stream: one kernel decode, chunk-segmented cumsums
+            dec = _kernel_decode(np.concatenate(pair_vals))
+            pad = np.zeros(2 * width, np.int32)
+            pad[:dec.size] = dec
+            meta = np.full((3, 3), 2**31 - 1, np.int32)
+            meta[:, :len(seg)] = np.array(seg, np.int32).T
+            s2, i2 = vk.pair_delta_restore(pad, *meta)
+            np.testing.assert_array_equal(np.asarray(s2)[:runs_seen],
+                                          np.concatenate(delta_src))
+            np.testing.assert_array_equal(np.asarray(i2)[:runs_seen],
+                                          np.concatenate(delta_pos))
+            delta = (s2, i2, runs_seen)
+        hs, nh = staged(direct_src)
+        hp, _ = staged(direct_pos)
         # run expansion + dst residues vs the codec's repeat-based restore
-        sp = np.zeros(max(nnz, 1), np.int32)
-        sp[:nnz] = srcs
-        ip = np.zeros(max(nnz, 1), np.int32)
-        ip[:nnz] = starts
-        esrc, smask = vk.expand_dcsr_index(sp, ip, nnz, n_e,
-                                           out_len=out_len)
-        np.testing.assert_array_equal(
-            np.asarray(esrc)[:n_e], np.repeat(srcs, runs))
-        res = codec.dst_delta_values(dst, starts, base)
-        rdec = _kernel_decode(res)
+        esrc, smask = vk.expand_dcsr_index(
+            (hs, delta[0]), (hp, delta[1]), (nh, delta[2]), n_e, VPAD,
+            out_len=out_len)
+        want_src = np.concatenate([np.zeros(0, np.int64)] + [
+            np.repeat(s, r) for s, r, _, _ in chunks])
+        np.testing.assert_array_equal(np.asarray(esrc)[:n_e], want_src)
+        rdec = _kernel_decode(np.concatenate(residues))
         rpad = np.zeros(out_len, np.int32)
         rpad[:rdec.size] = rdec
-        d2 = vk.dst_delta_restore(rpad, smask, base, n_e)
-        np.testing.assert_array_equal(np.asarray(d2)[:n_e], dst)
+        got = np.asarray(vk.dst_delta_restore(rpad, smask, base, n_e, esrc))
+        np.testing.assert_array_equal(got[0, :n_e], want_src)
+        np.testing.assert_array_equal(
+            got[1, :n_e], np.concatenate([d for _, _, d, _ in chunks]))
 
 
 def test_dst_restore_survives_int32_wrap_of_the_running_sum():
-    # every run restarts at its batch offset, so a chunk with many runs of
-    # large residues sums past 2**31 while each dst stays small
+    # every run restarts at its batch offset, so a batch with many runs of
+    # large residues sums past 2**31 while each dst stays small; src
+    # restarts every 2000 runs, as each chunk of a batch does
     n_runs, base = 6000, 64
     dst = base + np.repeat(np.arange(n_runs) % 7 + 2**20, 1)
     starts = np.arange(n_runs)
     res = codec.dst_delta_values(dst, starts, base)
     assert res.astype(np.int64).sum() > 2**31
     smask = np.ones(n_runs, np.int32)
-    got = vk.dst_delta_restore(res.astype(np.int32), smask, base, n_runs)
-    np.testing.assert_array_equal(np.asarray(got), dst)
+    src = (np.arange(n_runs) % 2000).astype(np.int32)
+    got = vk.dst_delta_restore(res.astype(np.int32), smask, base, n_runs,
+                               src)
+    np.testing.assert_array_equal(np.asarray(got), [src, dst])
 
 
 def test_expand_csr_index_matches_repeat():
+    # CSR chunks enter the batched expand as their rows of nonzero degree
+    # (rows of degree 0 at the start, inside and after the last nonzero
+    # row are left out): two chunks back to back, heads carrying
+    # slot * vpad + row, in two groups as wide as the output
     rng = np.random.default_rng(1)
-    v_src, vpad = 37, 48
-    deg = rng.integers(0, 4, v_src)
-    idx = np.zeros(vpad + 1, np.int32)
-    idx[1:v_src + 1] = np.cumsum(deg)
-    idx[v_src + 1:] = idx[v_src]
-    n_e = int(deg.sum())
-    esrc, smask = vk.expand_csr_index(idx, v_src, n_e, out_len=n_e + 5)
-    np.testing.assert_array_equal(
-        np.asarray(esrc)[:n_e], np.repeat(np.arange(v_src), deg))
-    starts = (np.cumsum(deg) - deg)[deg > 0]
-    exp_mask = np.zeros(n_e + 5, np.int32)
-    exp_mask[starts] = 1
+    v_src, vpad = 37, 64
+    degs = [rng.integers(0, 4, v_src) for _ in range(2)]
+    for deg in degs:
+        deg[[0, 5, -3, -2, -1]] = 0
+        deg[1] = 2
+    n_es = [int(d.sum()) for d in degs]
+    n_e = sum(n_es)
+    out_len = n_e + 5
+    groups = []
+    for c, (d, off) in enumerate(zip(degs, [0, n_es[0]])):
+        rows = np.flatnonzero(d)
+        heads = np.zeros((2, out_len), np.int32)
+        heads[0, :rows.size] = c * vpad + rows
+        heads[1, :rows.size] = (np.cumsum(d) - d)[rows] + off
+        groups.append((heads[0], heads[1], rows.size))
+    srcs, starts, live = zip(*groups)
+    esrc, smask = vk.expand_dcsr_index(srcs, starts, live, n_e, vpad,
+                                       out_len=out_len)
+    want = np.concatenate([np.repeat(np.arange(v_src), d) for d in degs])
+    np.testing.assert_array_equal(np.asarray(esrc)[:n_e], want)
+    exp_mask = np.zeros(out_len, np.int32)
+    for d, off in zip(degs, [0, n_es[0]]):
+        exp_mask[(np.cumsum(d) - d)[d > 0] + off] = 1
     np.testing.assert_array_equal(np.asarray(smask), exp_mask)
 
 
@@ -242,6 +311,121 @@ def test_device_decode_matches_host_per_chunk(built):
     assert checked > 0
 
 
+# ---------------------------------------------------------------------------
+# Batch decode: one dst batch's chunks in one device call == the host
+# decode of each chunk, concatenated
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def batch_stores(tmp_path_factory):
+    root = tmp_path_factory.mktemp("vk_batch")
+    # sparse RMAT, 4 partitions x 6 batches: one-edge chunks, CSR chunks
+    g = rmat_graph(8, 2, seed=0, weighted=True)
+    dg = build_dist_graph(g, make_spec(g, num_partitions=4, batch_size=16))
+    rmat = ChunkStore.build(dg, build_formats(dg), str(root / "rmat"))
+    # every third vertex points near the top of both partitions, whose
+    # batch is the whole partition: residues of about 2**16 at each of
+    # 2 x 21,846 run starts, so dst batch (0, 0) sums past 2**31 and each
+    # of its two chunks stays under
+    n = 2**17
+    s = np.arange(0, n, 3)
+    g = GraphData(n, np.concatenate([s, s]),
+                  np.concatenate([65535 - s % 1024, n - 1 - s % 1024]), None)
+    dg = build_dist_graph(g, make_spec(g, num_partitions=2,
+                                       batch_size=65536))
+    wrap = ChunkStore.build(dg, build_formats(dg), str(root / "wrap"))
+    return rmat, wrap
+
+
+def _chunks(store, q, k):
+    lay = store._layout_of(q)
+    return [p for p in range(store.num_partitions) if lay.edges[p, k] > 0]
+
+
+def _items(store):
+    for q in store.partitions:
+        for k in range(store.num_batches):
+            ps = _chunks(store, q, k)
+            if ps:
+                yield q, k, ps
+
+
+def _host_decode(store, q, k, chunks):
+    return [store.decode_chunk(q, p, k, rep,
+                               *store.read_chunk_bytes(q, p, k, rep)[:2])
+            for p, rep in chunks]
+
+
+def _case_mixed_reps(stores):
+    store = stores[0]
+    for q, k, ps in _items(store):
+        csr = [p for p in ps if store._layout_of(q).has_csr[p, k]]
+        if len(ps) >= 3 and csr:
+            reps = [REP_DCSR_DELTA, REP_DCSR]
+            return store, q, k, [
+                (p, REP_CSR if p == csr[0] else reps[i % 2])
+                for i, p in enumerate(ps)]
+    pytest.fail("no batch of three chunks with a CSR one")
+
+
+def _case_one_chunk(stores):
+    store = stores[0]
+    q, k, ps = next(_items(store))
+    return store, q, k, [(ps[0], REP_DCSR_DELTA)]
+
+
+def _case_one_edge_chunk(stores):
+    store = stores[0]
+    for q, k, ps in _items(store):
+        lay = store._layout_of(q)
+        if len(ps) > 1 and any(lay.edges[p, k] == 1 for p in ps):
+            return store, q, k, [(p, REP_DCSR_DELTA) for p in ps]
+    pytest.fail("no batch with a one-edge chunk")
+
+
+def _case_residue_sum_past_2_31(stores):
+    store = stores[1]
+    chunks = [(0, REP_DCSR_DELTA), (1, REP_DCSR)]
+    sums = []
+    for p, rep in chunks:
+        _, payload, _ = store.read_chunk_bytes(0, p, 0, rep)
+        vnb = int(store._layout_of(0).dstv_nb[p, 0])
+        n_e = int(store._layout_of(0).edges[p, 0])
+        sums.append(int(codec.varint_decode(payload[:vnb], n_e).sum()))
+    assert max(sums) < 2**31 < sum(sums)
+    return store, 0, 0, chunks
+
+
+def _case_src_restarts_lower(stores):
+    store = stores[0]
+    for q, k, ps in _items(store):
+        chunks = [(p, REP_DCSR_DELTA) for p in ps]
+        if len(ps) >= 2:
+            (s0, _, _), (s1, _, _) = _host_decode(store, q, k, chunks[:2])
+            if s1[0] < s0[-1]:
+                return store, q, k, chunks
+    pytest.fail("no batch whose second chunk starts at a lower src")
+
+
+@pytest.mark.parametrize("case", [
+    _case_mixed_reps, _case_one_chunk, _case_one_edge_chunk,
+    _case_residue_sum_past_2_31, _case_src_restarts_lower,
+], ids=lambda f: f.__name__[len("_case_"):])
+def test_decode_batch_matches_host_chunks(batch_stores, case):
+    store, q, k, chunks = case(batch_stores)
+    raw = [(p, rep, store.read_chunk_bytes(q, p, k, rep))
+           for p, rep in chunks]
+    src, part, dst, data = store.decode_batch_device(q, k, raw)
+    host = _host_decode(store, q, k, chunks)
+    np.testing.assert_array_equal(src, np.concatenate([h[0] for h in host]))
+    np.testing.assert_array_equal(
+        part, np.concatenate([np.full(h[0].size, p, np.int32)
+                              for (p, _), h in zip(chunks, host)]))
+    np.testing.assert_array_equal(dst, np.concatenate([h[1] for h in host]))
+    np.testing.assert_array_equal(data,
+                                  np.concatenate([h[2] for h in host]))
+
+
 def test_device_decode_rejects_uncompressed_store(built):
     g, dg, fm, root = built
     store = ChunkStore.build(dg, fm, str(root / "uncomp"), compression=False)
@@ -268,6 +452,40 @@ def test_device_decode_requires_compression(built):
         Engine(dg, fm, EngineConfig(device_decode=True, compression=False))
 
 
+@pytest.mark.parametrize("sizes,fits", [
+    ([2**27] * 8, True), ([2**27 + 1] * 8, False), ([2**28] * 8, False),
+    ([2**30], True), ([2**30 + 1], False), ([5, 2**27 + 1, 3, 7], True),
+])
+def test_device_decode_fits_the_int32_slot_domain(sizes, fits):
+    # a batch's run heads reach num_partitions x the largest partition's
+    # size rounded up to a power of two
+    assert chunkstore.device_decode_fits(np.array(sizes)) is fits
+
+
+def test_store_past_the_int32_domain_decodes_on_the_host(built,
+                                                          monkeypatch):
+    import repro.core.engine as engine_mod
+    import repro.kernels.csr_spmv as csr_spmv
+    g, dg, fm, root = built
+    ooc = (EngineConfig(executor="ooc"),
+           ChunkStore.build(dg, fm, str(root / "fits_ooc")))
+    dist = (EngineConfig(executor="dist_ooc", num_workers=2),
+            ChunkStore.build_sharded(dg, fm, str(root / "fits_dist"), 2))
+    monkeypatch.setattr(csr_spmv, "default_interpret", lambda: False)
+    for cfg, store in (ooc, dist):
+        assert Engine(dg, fm, cfg, store=store).device_decode
+    monkeypatch.setattr(engine_mod, "device_decode_fits", lambda sizes: False)
+    # auto falls back to the host decode; asking for the device fails
+    # when the engine is built, not on the prefetch thread's first decode
+    for cfg, store in (ooc, dist):
+        assert not Engine(dg, fm, cfg, store=store).device_decode
+        with pytest.raises(ValueError, match="2\\*\\*31"):
+            Engine(dg, fm, dataclasses.replace(cfg, device_decode=True),
+                   store=store)
+    # LOCAL reads no chunk store: the flag stays as given
+    assert Engine(dg, fm, EngineConfig(device_decode=True)).device_decode
+
+
 def test_unweighted_store_elides_value_column(built):
     g, dg, fm, root = built
     store = ChunkStore.build(dg, fm, str(root / "elide"))
@@ -289,10 +507,15 @@ def test_unweighted_store_elides_value_column(built):
 # Engine-level: device_decode on/off bit-identity, all four executors
 # ---------------------------------------------------------------------------
 
-def _run_all(engine, g):
+def _run_all_lazily(engine, g):
     src = int(np.argmax(g.out_degrees()))
-    return [alg.pagerank(engine, 3), alg.bfs(engine, src),
-            alg.sssp(engine, src)]
+    yield alg.pagerank(engine, 3)
+    yield alg.bfs(engine, src)
+    yield alg.sssp(engine, src)
+
+
+def _run_all(engine, g):
+    return list(_run_all_lazily(engine, g))
 
 
 def _assert_bit_identical(outs_a, outs_b):
@@ -300,7 +523,7 @@ def _assert_bit_identical(outs_a, outs_b):
         np.testing.assert_array_equal(np.asarray(va), np.asarray(vb))
         assert sa.per_iter_return == sb.per_iter_return
         for k in sa.counters:
-            if k != "measured_chunks_device_decoded":
+            if k not in DEVICE_DECODE_KEYS:
                 assert sa.counters[k] == sb.counters[k], k
 
 
@@ -311,20 +534,40 @@ def test_local_device_decode_on_off_bit_identical(built):
     _assert_bit_identical(_run_all(on, g), _run_all(off, g))
 
 
-def test_ooc_device_decode_on_off_bit_identical(built):
+def test_ooc_device_decode_on_off_bit_identical(built, monkeypatch):
     g, dg, fm, root = built
     on = Engine(dg, fm, EngineConfig(executor="ooc", device_decode=True),
                 store=ChunkStore.build(dg, fm, str(root / "ooc_on")))
     off = Engine(dg, fm, EngineConfig(executor="ooc", device_decode=False),
                  store=ChunkStore.build(dg, fm, str(root / "ooc_off")))
+    # count the nonempty work items the prefetchers hand the executor
+    items = []
+    stream = ChunkPrefetcher.__iter__
+
+    def counted(self):
+        for w in stream(self):
+            items.append(w.n_chunks > 0)
+            yield w
+    monkeypatch.setattr(ChunkPrefetcher, "__iter__", counted)
+
+    def run_all(engine):
+        outs = []
+        for out in _run_all_lazily(engine, g):
+            outs.append((*out, sum(items)))
+            items.clear()
+        return outs
     # verify_io is on by default: every call cross-checks measured==model
-    outs_on, outs_off = _run_all(on, g), _run_all(off, g)
-    _assert_bit_identical(outs_on, outs_off)
-    for _, s in outs_on:
+    outs_on, outs_off = run_all(on), run_all(off)
+    _assert_bit_identical([o[:2] for o in outs_on],
+                          [o[:2] for o in outs_off])
+    for _, s, n_items in outs_on:
         assert s.counters["measured_chunks_device_decoded"] == \
             s.counters["measured_chunks_read"]
-    for _, s in outs_off:
+        assert n_items > 0
+        assert s.counters["measured_device_decode_calls"] == n_items
+    for _, s, _ in outs_off:
         assert s.counters["measured_chunks_device_decoded"] == 0
+        assert s.counters["measured_device_decode_calls"] == 0
 
 
 @pytest.mark.parametrize("parallel", [False, True])
@@ -350,6 +593,8 @@ def test_dist_device_decode_on_off_bit_identical(built, parallel):
                    - s.counters["net_bytes"]) < 1e-3
     for _, s in outs_on:
         assert s.counters["measured_chunks_device_decoded"] > 0
+        assert 0 < s.counters["measured_device_decode_calls"] <= \
+            s.counters["measured_chunks_device_decoded"]
 
 
 SHARD_MAP_CODE = """
