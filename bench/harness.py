@@ -3,12 +3,14 @@ the reference, and the result line.
 
 Set-up generates the configuration's graph from the seed, builds the
 program's partition, chunk formats and (out of core) chunk store, opens
-the ``Engine`` and runs the traffic's warm-up jobs, which compile or load
-from the persistent cache every program the window runs (a program the
-engine jits afresh on every call compiles again in the window, as it
-does for a user; ``window_compiles`` counts it).  The window runs
-the traffic's jobs back to back and closes at the end of the first job
-that finishes at or after ``seconds``, so it holds whole jobs only.  Once
+the ``Engine`` (over a mesh of the cell's chips, one partition on each,
+where the cell has more than one) and runs the traffic's warm-up jobs,
+which compile or load from the persistent cache every program the window
+runs (a program the engine jits afresh on every call compiles again in
+the window, as it does for a user; ``window_compiles`` counts it).  The
+window runs the traffic's jobs back to back and closes at the end of the
+first job that finishes at or after ``seconds``, so it holds whole jobs
+only.  Once
 it has closed and the device's peak memory has been read, the program's
 state is freed and every job's answer is compared with the benchmark's
 own numpy reference (``algorithms/<name>.py`` of the traffic's algorithm).
@@ -70,8 +72,14 @@ class System:
     stages: dict            # set-up stage -> seconds
 
 
-def build_system(config: dict, seed: int, workdir: str) -> System:
-    """The configuration's graph from ``seed``, and the engine over it."""
+def build_system(config: dict, seed: int, workdir: str,
+                 devices: list) -> System:
+    """The configuration's graph from ``seed``, and the engine over it.
+
+    On one device the engine is the configuration's executor (LOCAL or
+    OOC).  Over several it is SHARD_MAP: a ``("part",)`` mesh of
+    ``devices``, one partition on each."""
+    import jax
     from repro.core import (ChunkStore, Engine, EngineConfig,
                             build_dist_graph, build_formats, make_spec)
     from repro.data.graphs import GraphData
@@ -97,7 +105,12 @@ def build_system(config: dict, seed: int, workdir: str) -> System:
     if engine_cfg.executor == "ooc":
         store = ChunkStore.build(dist, fmts, os.path.join(workdir, "store"))
         stage("store")
-    engine = Engine(dist, fmts, engine_cfg, store=store)
+    if len(devices) > 1:
+        mesh = jax.sharding.Mesh(np.asarray(devices), ("part",))
+        engine = Engine(dist, fmts, engine_cfg, store=store, mesh=mesh,
+                        axis="part")
+    else:
+        engine = Engine(dist, fmts, engine_cfg, store=store)
     stage("engine")
     return System(graph, engine, stages)
 
@@ -224,6 +237,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     ``require_chip`` off lets the tests drive a run on the CPU, without
     the persistent compilation cache."""
     t_process = time.perf_counter() if t_process is None else t_process
+    parts = int(cell.config["num_partitions"])
+    if cell.chips > 1 and parts != cell.chips:
+        raise catalog.CatalogError(
+            f"cell {cell.name!r} spans {cell.chips} chips, so its "
+            f"configuration needs num_partitions {cell.chips} (one per "
+            f"chip), not {parts}")
     if require_chip:
         devices = check_chip(cell.chips)
         # the program's own cache set-up, as its entry points run it
@@ -232,6 +251,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     else:
         import jax
         devices = jax.devices()[:cell.chips]
+        if len(devices) < cell.chips:
+            raise NoChip(f"the cell needs {cell.chips} devices; JAX sees "
+                         f"{len(devices)}")
     meter = CompileMeter()
     workdir = tempfile.mkdtemp(prefix="bench-")
     try:
@@ -247,7 +269,7 @@ def _run(cell, seed, seconds, trace, devices, meter, workdir,
     import jax
     log(f"{cell.name}: seed {seed}, {seconds} s window, trace {int(trace)}, "
         f"{len(devices)} x {devices[0].device_kind}")
-    system = build_system(cell.config, seed, workdir)
+    system = build_system(cell.config, seed, workdir, devices)
     alg = catalog.algorithm(cell.traffic["algorithm"])
     warmup, jobs = alg.jobs(cell.traffic, system.graph, seed)
     t = time.perf_counter()

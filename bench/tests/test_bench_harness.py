@@ -1,6 +1,7 @@
 """CPU tests of the benchmark harness that need no chip: discovery by name,
 the Graph500 generator, the copied references, the per-layer readers'
 arithmetic, and the entry point's refusal to run without a TPU."""
+import dataclasses
 import json
 import os
 import subprocess
@@ -58,14 +59,28 @@ def test_benchmark_files_are_each_others():
 
 def test_a_new_cell_is_an_entry_and_files():
     bench = json.loads(json.dumps(BENCH))
-    bench["workloads"].append({"name": "local-bfs", "config":
+    bench["workloads"].append({"name": "made-up-local-bfs", "config":
                                "g500-s19-local", "traffic": "bfs",
                                "chips": 1, "why": "test"})
-    cell = catalog.find_cell("local-bfs", bench)
+    cell = catalog.find_cell("made-up-local-bfs", bench)
     assert cell.traffic["algorithm"] == "bfs"
     assert cell.config["engine"].get("executor", "auto") == "auto"
     # metrics that list their cells leave an unlisted cell out
     assert cell.per_layer == []
+
+
+def test_a_cell_across_chips_needs_a_partition_per_chip_and_the_chips():
+    cell = catalog.find_cell("local-bfs")               # num_partitions 8
+    cell.config = dict(cell.config, scale=SCALE)
+    with pytest.raises(catalog.CatalogError, match="num_partitions 4"):
+        harness.run_cell(dataclasses.replace(cell, chips=4), 1, 0.0, False,
+                         require_chip=False)
+    import jax
+    n = len(jax.devices()) + 1
+    cell = dataclasses.replace(cell, chips=n, config=dict(
+        cell.config, num_partitions=n))
+    with pytest.raises(harness.NoChip):
+        harness.run_cell(cell, 1, 0.0, False, require_chip=False)
 
 
 def test_unknown_names_are_errors():

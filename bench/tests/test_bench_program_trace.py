@@ -301,4 +301,5 @@ def test_new_metrics_are_entries_with_readers():
         assert m["moves"] == "evps" and m["better"] == "lower"
         assert os.path.exists(os.path.join(
             catalog.BENCH_DIR, "layer_metrics", f"{name}.py"))
-    assert entries["combine_gather_device_ms"]["workloads"] == ["local-pagerank"]
+    assert entries["combine_gather_device_ms"]["workloads"] == [
+        "local-pagerank", "local-bfs"]
