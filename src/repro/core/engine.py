@@ -208,22 +208,22 @@ class EngineConfig:
 
     physical_sparse_exchange: bool | None = None
     """SHARD_MAP only: realize the adaptive wire physically (DESIGN.md
-    §12).  Each iteration derives a per-peer capacity bound from the same
-    ``phases.routing_counts`` structure that prices the wire (a ``pmax``'d
-    max over per-(p, q) live counts, rounded to a pow2 bucket so
-    recompilation stays bounded) and arbitrates — with the same cost
-    comparison ``exchange.choose_wire_format`` uses — between a compacted
-    ``all_to_all`` (``capacity`` (value, source-index) pairs per peer;
-    the multi-query panel adds per-query presence flags over ONE shared
-    index stream) and the legacy dense slab.  A ``pmax``'d overflow check
-    falls back to the dense path in-graph if the live counts ever exceed
-    the capacity bucket, so results are bit-identical to the dense
-    exchange either way; the chosen path's payload-element volume is
-    reported as the ``net_payload_elems`` / ``measured_net_payload_elems``
-    counter pair and cross-checked under ``verify_io``.  ``None`` (auto)
-    enables it exactly when a mesh is passed; ``True`` without a mesh is
-    an error (the other executors have no in-mesh collective to
-    realize)."""
+    §12).  The executor fixes a short ladder of power-of-two per-peer
+    capacities when it is built (``executor.wire_ladder``: the largest
+    capacity at which ``exchange.choose_physical_exchange`` still prefers
+    the compacted collective, then down by a constant factor), and its one
+    compiled step picks, every iteration, the first rung that holds the
+    ``pmax``'d max per-(p, q) live count of the send decision, else the
+    dense slab.  A compacted rung ships ``capacity`` (value,
+    source-index) pairs per peer (the multi-query panel adds per-query
+    presence flags over ONE shared index stream) and re-densifies on
+    receipt, so results are bit-identical to the dense exchange; the
+    shipped payload is reported as the ``net_payload_elems`` /
+    ``measured_net_payload_elems`` counter pair and cross-checked under
+    ``verify_io``.  ``None`` (auto) enables it exactly when a mesh is
+    passed; ``False`` keeps the dense slab (the tests' reference);
+    ``True`` without a mesh is an error (the other executors have no
+    in-mesh collective to realize)."""
 
     num_queries: int = 1
     """Q for the multi-query serving surface (``process_edges_multi`` /
@@ -1098,7 +1098,7 @@ class Engine:
         if fn is None:
             fn = _executor.make_sharded_pe(
                 self, signal_fn, slot_fn, monoid, apply_fn, backend,
-                mode_meta, active is not None)
+                mode_meta)
             if cache_key is not None:
                 self._pe_cache[cache_key] = fn
         bt = self._block_garrs if backend == "block_csr" else None
@@ -1247,8 +1247,7 @@ class Engine:
             return fn(state, active, self.graph, self.fmts, self.global_id)
         if fn is None:
             fn = _multiquery.make_sharded_pe_mq(
-                self, signal_fn, slot_fn, monoid, apply_fn, nq,
-                active is not None)
+                self, signal_fn, slot_fn, monoid, apply_fn, nq)
             if cache_key is not None:
                 self._pe_cache[cache_key] = fn
         out = fn(state, active, self._garrs)

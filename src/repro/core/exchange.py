@@ -158,8 +158,10 @@ def choose_wire_format(count: int, v_max: int, msg_bytes: int,
 
 def choose_physical_exchange(capacity: int, v_max: int, msg_bytes: int,
                              nq: int = 1) -> bool:
-    """Arbitrate the SHARD_MAP physical wire (DESIGN.md §12): True means
-    ship the compacted collective this iteration, False the dense slab.
+    """Price the SHARD_MAP physical wire (DESIGN.md §12): True where a
+    compacted collective of ``capacity`` per peer costs less than the
+    dense slab.  The executor's wire ladder (``executor.wire_ladder``)
+    tops out at the largest power of two where this holds.
 
     This is the SAME cost comparison :func:`choose_wire_format` runs for
     the serialized wire, applied to the collective's per-peer volume: a

@@ -390,46 +390,67 @@ def _compacted_exchange(msg_row, sendmask, capacity, axis):
     return recv_msg, recv_mask, measured
 
 
-def make_sharded_probe(engine, has_active, garrs_keys, nq=1):
-    """Capacity probe for the physical sparse exchange: the ``pmax``'d
-    max per-(p, q) live count of this iteration's send decision (for
-    multi-query, of the UNION send mask — the panel's capacity bound).
+# The SHARD_MAP wire ladder (DESIGN.md §12): adjacent rungs differ by
+# this factor, and no rung is below the floor.  Every rung is one more
+# branch compiled into the step.
+WIRE_LADDER_STEP = 8
+WIRE_LADDER_FLOOR = 8
 
-    The compacted collective's ``capacity`` is a static shape, so it must
-    be known before the step traces; this tiny shard_map pass re-runs
-    ONLY the phase-2 filter (no signal values, no combine) and returns
-    the bound the host buckets to a pow2 capacity.  Deterministic — the
-    jitted step recomputes the identical sendmask, so the bound is exact
-    and the in-step overflow fallback can never fire from probe skew."""
-    cfg = engine.config
-    mesh, axis = engine.mesh, engine.axis
 
-    def pstep(active, garrs):
-        vertex_valid = garrs["vertex_valid"]                     # [1, V]
-        union_sm = None
-        for j in range(nq):
-            if active is None:
-                amask = vertex_valid
-            elif nq == 1:
-                amask = active & vertex_valid
-            else:
-                amask = active[..., j] & vertex_valid
-            m_p = jnp.sum(amask, dtype=jnp.float32)
-            sm = phases.filter_sendmask(
-                amask[0], garrs["need"][0], garrs["need_counts"][0],
-                m_p, cfg)
-            union_sm = sm if union_sm is None else (union_sm | sm)
-        cmax = jnp.max(phases.routing_counts(union_sm))
-        return jax.lax.pmax(cmax, axis)
+def wire_ladder(v_max: int, msg_bytes: int, nq: int = 1) -> tuple:
+    """The compacted wire's capacities for one SHARD_MAP executor,
+    ascending: the largest power of two at which
+    ``exchange.choose_physical_exchange`` still ships the compacted
+    collective, then down by ``WIRE_LADDER_STEP`` while at least
+    ``WIRE_LADDER_FLOOR``.  Empty where even the floor pays the dense
+    slab's price."""
+    def pays(cap):
+        return exchange_mod.choose_physical_exchange(cap, v_max, msg_bytes,
+                                                     nq)
+    if not pays(WIRE_LADDER_FLOOR):
+        return ()
+    cap = WIRE_LADDER_FLOOR
+    while pays(2 * cap):
+        cap *= 2
+    rungs = []
+    while cap >= WIRE_LADDER_FLOOR:
+        rungs.append(cap)
+        cap //= WIRE_LADDER_STEP
+    return tuple(reversed(rungs))
 
-    in_specs = (P(axis) if has_active else None,
-                {k: P(axis) for k in garrs_keys})
-    return jax.jit(jax.shard_map(pstep, mesh=mesh, in_specs=in_specs,
-                                 out_specs=P(), check_vma=False))
+
+def sharded_wire(counts, ladder, dense, compacted, elems_model, axis,
+                 counters):
+    """The physical wire of one SHARD_MAP exchange (DESIGN.md §12), for
+    the solo and the Q-panel step alike: the first ``ladder`` rung that
+    holds this iteration's ``pmax``'d max per-peer live count (``counts``
+    per destination), else the dense slab.  The rung index is the same on
+    every shard, so every shard takes the same ``lax.switch`` branch and
+    the collectives stay in lockstep; every branch hands phases 3-4 the
+    dense receive layout, so results are bit-identical whichever ships.
+
+    ``dense()`` and ``compacted(capacity)`` return (received values,
+    received mask, measured payload elements); ``elems_model(capacity)``
+    prices a rung, ``None`` the dense slab.  Writes the wire counters and
+    returns (received values, received mask)."""
+    live = jax.lax.pmax(jnp.max(counts), axis)
+    rung = jnp.sum(live > jnp.asarray(ladder, live.dtype))
+    recv_vals, recv_mask, measured = jax.lax.switch(
+        rung, [functools.partial(compacted, c) for c in ladder] + [dense])
+    elems = jnp.asarray([elems_model(c) for c in ladder + (None,)],
+                        jnp.float32)
+    dense_f = (rung == len(ladder)).astype(jnp.float32)
+    is0 = (jax.lax.axis_index(axis) == 0).astype(jnp.float32)
+    counters["net_payload_elems_dense"] = elems[-1]
+    counters["net_payload_elems"] = elems[rung]
+    counters["measured_net_payload_elems"] = measured
+    counters["exchange_compacted_iters"] = (1.0 - dense_f) * is0
+    counters["exchange_dense_iters"] = dense_f * is0
+    return recv_vals, recv_mask
 
 
 def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
-                    mode_meta, has_active):
+                    mode_meta):
     cfg = engine.config
     spec = engine.graph.spec
     p_cnt = spec.num_partitions
@@ -441,14 +462,15 @@ def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
               if backend == "block_csr" else None)
     interpret = default_interpret()
     counter_keys = engine.counter_keys
-    physical = engine.physical_sparse_exchange
+    ladder = (wire_ladder(spec.v_max, cfg.msg_bytes)
+              if engine.physical_sparse_exchange else ())
     dp = functools.partial(
         _dest_phases, slot_fn=slot_fn, monoid=monoid, spec=spec, cfg=cfg,
         backend=backend, part_sizes=part_sizes, gamma=gamma,
         mode_meta=mode_meta, rb_map=rb_map, bt_static=bt_static,
         interpret=interpret)
 
-    def step(state, active, garrs, bt, vals, wire_capacity=None):
+    def step(state, active, garrs, bt, vals):
         counters = _zero_counters(counter_keys)
         vertex_valid = garrs["vertex_valid"]               # [1, V]
         amask = vertex_valid if active is None else (active & vertex_valid)
@@ -480,39 +502,14 @@ def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
                                        gap_bytes=gapb, uniform=unib))
             counters["net_bytes_nofilter"] = ((p_cnt - 1) * m_p
                                               * (cfg.msg_bytes + 4))
-            # Physical wire (DESIGN.md §12): dense slab, or the compacted
-            # collective the host arbitrated for this iteration's capacity
-            # bucket — with an in-graph overflow fallback to dense (the
-            # pmax'd predicate is identical on every shard, so the branch is
-            # uniform and the collectives stay in lockstep).  Either way the
-            # combine sees the exact dense [P, V] layout, so results are
-            # bit-identical to the legacy exchange.
-            is0 = (my == 0).astype(jnp.float32)
-            dense_elems = jnp.float32(
-                phases.net_payload_elems_model(p_cnt, spec.v_max))
-            counters["net_payload_elems_dense"] = dense_elems
-            if wire_capacity is None:
-                recv_msg, recv_mask, measured = _dense_exchange(
-                    msg[0], sendmask, axis)
-                counters["net_payload_elems"] = dense_elems
-                counters["measured_net_payload_elems"] = measured
-                counters["exchange_dense_iters"] = is0
-            else:
-                overflow = jax.lax.pmax(jnp.max(counts), axis) > wire_capacity
-                recv_msg, recv_mask, measured = jax.lax.cond(
-                    overflow,
-                    lambda _: _dense_exchange(msg[0], sendmask, axis),
-                    lambda _: _compacted_exchange(msg[0], sendmask,
-                                                  wire_capacity, axis),
-                    None)
-                comp_elems = jnp.float32(phases.net_payload_elems_model(
-                    p_cnt, spec.v_max, capacity=wire_capacity))
-                ovf_f = overflow.astype(jnp.float32)
-                counters["net_payload_elems"] = jnp.where(
-                    overflow, dense_elems, comp_elems)
-                counters["measured_net_payload_elems"] = measured
-                counters["exchange_compacted_iters"] = (1.0 - ovf_f) * is0
-                counters["exchange_dense_iters"] = ovf_f * is0
+            # Physical wire (DESIGN.md §12)
+            recv_msg, recv_mask = sharded_wire(
+                counts, ladder,
+                lambda: _dense_exchange(msg[0], sendmask, axis),
+                lambda c: _compacted_exchange(msg[0], sendmask, c, axis),
+                functools.partial(phases.net_payload_elems_model, p_cnt,
+                                  spec.v_max),
+                axis, counters)
 
         # Phases 3 + 4 on this shard's destination view (in-HBM ChunkSource)
         d = {k: v[0] for k, v in HBMChunkSource.dest_arrays(garrs).items()}
@@ -538,41 +535,11 @@ def make_sharded_pe(engine, signal_fn, slot_fn, monoid, apply_fn, backend,
         counters = {k: jax.lax.psum(v, axis) for k, v in counters.items()}
         return new_state, new_active, total, counters
 
-    jitted = {}
-    probe = []
-
-    def run_sharded(state, active, garrs, bt, vals):
-        wire_capacity = None
-        if physical:
-            if not probe:
-                probe.append(make_sharded_probe(engine, has_active,
-                                                tuple(garrs)))
-            cap = sparse_collectives.capacity_bucket(
-                float(probe[0](active, garrs)))
-            if exchange_mod.choose_physical_exchange(cap, spec.v_max,
-                                                     cfg.msg_bytes):
-                wire_capacity = cap
-        skey = (tuple(sorted(state)), bt is None,
-                None if vals is None else tuple(sorted(vals)),
-                wire_capacity)
-        fn = jitted.get(skey)
-        if fn is None:
-            in_specs = ({k: P(axis) for k in state},
-                        P(axis) if has_active else None,
-                        {k: P(axis) for k in garrs},
-                        None if bt is None else P(axis),
-                        None if vals is None else {k: P(axis) for k in vals})
-            out_specs = ({k: P(axis) for k in state}, P(axis), P(),
-                         {k: P() for k in counter_keys})
-            # check_vma=False: the Pallas combine kernel's out_shape
-            # carries no varying-mesh-axis annotation
-            fn = jax.jit(jax.shard_map(
-                functools.partial(step, wire_capacity=wire_capacity),
-                mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False))
-            jitted[skey] = fn
-        return fn(state, active, garrs, bt, vals)
-    return run_sharded
+    # check_vma=False: the Pallas combine kernel's out_shape carries no
+    # varying-mesh-axis annotation
+    return jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=P(axis),
+        out_specs=(P(axis), P(axis), P(), P()), check_vma=False))
 
 
 # ---------------------------------------------------------------------------

@@ -51,8 +51,8 @@ from repro.core.chunkstore import REP_CSR, REP_DCSR, REP_DCSR_DELTA, \
 from repro.core.executor import (
     DestHeader, _apply_and_account, _batch_any, _block_dest_vectors,
     _combine_stream_batch, _max_tiles_per_batch_row, _stream_tile_layout,
-    _stream_value_tiles, _zero_counters, make_sharded_probe,
-    run_worker_pool,
+    _stream_value_tiles, _zero_counters, run_worker_pool, sharded_wire,
+    wire_ladder,
 )
 from repro.kernels.csr_spmv import block_csr_combine_mq, default_interpret
 from repro.utils import ceil_div, token_ctx
@@ -289,8 +289,7 @@ def make_local_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, nq):
 # SHARD_MAP executor (mesh axis, one panel all_to_all)
 # ---------------------------------------------------------------------------
 
-def make_sharded_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, nq,
-                       has_active):
+def make_sharded_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, nq):
     """Multi-query SHARD_MAP ProcessEdges (segment backend).
 
     The exchange ships ONE [P, V, Q] panel ``all_to_all`` (a pure per-column
@@ -304,10 +303,11 @@ def make_sharded_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, nq,
     gamma = engine.fmts.gamma
     part_sizes = jnp.asarray(spec.partition_sizes(), jnp.float32)
     counter_keys = engine.counter_keys
-    physical = engine.physical_sparse_exchange
+    ladder = (wire_ladder(v_max, cfg.msg_bytes, nq)
+              if engine.physical_sparse_exchange else ())
     mb = cfg.msg_bytes + 4
 
-    def step(state, active, garrs, wire_capacity=None):
+    def step(state, active, garrs):
         counters = _zero_counters(counter_keys)
         vertex_valid = garrs["vertex_valid"]                 # [1, V]
         my = jax.lax.axis_index(axis)
@@ -355,53 +355,35 @@ def make_sharded_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, nq,
         # ONE panel exchange: all_to_all permutes rows per column, so each
         # query's received view is bit-identical to its solo exchange.
         # Physically (DESIGN.md §12) the panel ships either the dense
-        # [P, V, nq] slab or the union-compacted panel the host
-        # arbitrated — ONE shared source-index stream per peer plus nq
-        # value columns and nq presence flags, the collective twin of the
-        # FMT_MQPANEL wire pricing — with the same pmax'd overflow
-        # fallback as the solo path.
+        # [P, V, nq] slab or the union-compacted panel — ONE shared
+        # source-index stream per peer plus nq value columns and nq
+        # presence flags, the collective twin of the FMT_MQPANEL wire
+        # pricing — chosen as for the solo exchange.
         send_valsp = jnp.stack([m[0] for m in msgs], axis=-1)  # [V, nq]
         send_maskp = jnp.stack(sendmasks, axis=-1)            # [P, V, nq]
 
-        def dense_panel(_):
+        def dense_panel():
             sv = jnp.where(send_maskp, send_valsp[None], 0)   # [P, V, nq]
             rv = jax.lax.all_to_all(sv, axis, 0, 0, tiled=True)
             rm = jax.lax.all_to_all(send_maskp.astype(jnp.int8), axis,
                                     0, 0, tiled=True) > 0     # [P, V, nq]
             return rv, rm, jnp.float32((p_cnt - 1) * 2 * sv[0].size)
 
-        def compacted_panel(_):
+        def compacted_panel(capacity):
             rv, rm, ridx, _ = \
                 sparse_collectives.masked_compacted_all_to_all_mq(
-                    send_valsp, send_maskp, wire_capacity, axis)
+                    send_valsp, send_maskp, capacity, axis)
             rvf, rmf = sparse_collectives.compacted_scatter_back_mq(
                 rv, rm, ridx, v_max)
             measured = jnp.float32(
                 (p_cnt - 1) * (rv[0].size + rm[0].size + ridx[0].size))
             return rvf, rmf, measured
 
-        is0 = (my == 0).astype(jnp.float32)
-        dense_elems = jnp.float32(
-            phases.net_payload_elems_model(p_cnt, v_max, nq=nq))
-        counters["net_payload_elems_dense"] = dense_elems
-        if wire_capacity is None:
-            recv_vals, recv_maskp, measured = dense_panel(None)
-            counters["net_payload_elems"] = dense_elems
-            counters["measured_net_payload_elems"] = measured
-            counters["exchange_dense_iters"] = is0
-        else:
-            overflow = jax.lax.pmax(jnp.max(ucounts),
-                                    axis) > wire_capacity
-            recv_vals, recv_maskp, measured = jax.lax.cond(
-                overflow, dense_panel, compacted_panel, None)
-            comp_elems = jnp.float32(phases.net_payload_elems_model(
-                p_cnt, v_max, capacity=wire_capacity, nq=nq))
-            ovf_f = overflow.astype(jnp.float32)
-            counters["net_payload_elems"] = jnp.where(
-                overflow, dense_elems, comp_elems)
-            counters["measured_net_payload_elems"] = measured
-            counters["exchange_compacted_iters"] = (1.0 - ovf_f) * is0
-            counters["exchange_dense_iters"] = ovf_f * is0
+        recv_vals, recv_maskp = sharded_wire(
+            ucounts, ladder, dense_panel, compacted_panel,
+            functools.partial(phases.net_payload_elems_model, p_cnt, v_max,
+                              nq=nq),
+            axis, counters)
 
         # Phase 3 + chunk model over the union of the received columns.
         d = {k: v[0] for k, v in HBMChunkSource.dest_arrays(garrs).items()}
@@ -455,35 +437,9 @@ def make_sharded_pe_mq(engine, signal_fn, slot_fn, monoid, apply_fn, nq,
         counters = {k: jax.lax.psum(v, axis) for k, v in counters.items()}
         return new_state, new_active, totals, counters
 
-    jitted = {}
-    probe = []
-
-    def run_sharded(state, active, garrs):
-        wire_capacity = None
-        if physical:
-            if not probe:
-                probe.append(make_sharded_probe(engine, has_active,
-                                                tuple(garrs), nq=nq))
-            cap = sparse_collectives.capacity_bucket(
-                float(probe[0](active, garrs)))
-            if exchange_mod.choose_physical_exchange(cap, v_max,
-                                                     cfg.msg_bytes, nq=nq):
-                wire_capacity = cap
-        skey = (tuple(sorted(state)), wire_capacity)
-        fn = jitted.get(skey)
-        if fn is None:
-            in_specs = ({k: P(axis) for k in state},
-                        P(axis) if has_active else None,
-                        {k: P(axis) for k in garrs})
-            out_specs = ({k: P(axis) for k in state}, P(axis), P(),
-                         {k: P() for k in engine.counter_keys})
-            fn = jax.jit(jax.shard_map(
-                functools.partial(step, wire_capacity=wire_capacity),
-                mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False))
-            jitted[skey] = fn
-        return fn(state, active, garrs)
-    return run_sharded
+    return jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=P(axis),
+        out_specs=(P(axis), P(axis), P(), P()), check_vma=False))
 
 
 # ---------------------------------------------------------------------------

@@ -182,22 +182,6 @@ def filtered_all_to_all(payload: jnp.ndarray, send_mask: jnp.ndarray,
     return recv, rmask
 
 
-def capacity_bucket(count: int, floor: int = 8) -> int:
-    """Round a live-count bound up to a power-of-two capacity bucket.
-
-    The compacted collectives take ``capacity`` as a static shape, so a
-    raw per-iteration maximum would recompile the exchange for every new
-    frontier size.  Bucketing to pow2 (same idiom as the wire decoder's
-    scratch buckets in :mod:`repro.core.exchange`) bounds the number of
-    compiled variants at ``log2(v_max)`` per algorithm while never
-    undershooting the true bound — so the overflow fallback below is a
-    hardening backstop, not a steady-state path."""
-    n = max(int(count), 1)
-    if n <= floor:
-        return floor
-    return 1 << (n - 1).bit_length()
-
-
 def compacted_all_to_all(payload: jnp.ndarray, dest: jnp.ndarray,
                          capacity: int, axis: str):
     """DCSR-analogue exchange: compact live entries per destination before

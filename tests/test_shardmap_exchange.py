@@ -10,7 +10,10 @@ Two subprocess suites on 8 forced host devices:
   to the dense exchange for all four algorithms plus multi-BFS, the
   ``measured_net_payload_elems == net_payload_elems`` audit holds, and
   compacted wins strictly on selective iterations while PageRank's
-  all-active frontier arbitrates dense.
+  all-active frontier arbitrates dense;
+* one step per graph — a solo and a multi-query BFS whose levels cross
+  two rungs of the wire ladder and the dense slab trace the sharded step
+  once per engine, and equal the dense exchange.
 
 Deterministic twins of the hypothesis properties in
 ``test_sparse_collectives.py`` — these must run even where hypothesis
@@ -51,15 +54,14 @@ dest = rng.integers(-1, PCNT, size=(PCNT, V)).astype(np.int32)
 payload = rng.normal(size=(PCNT, V, 3)).astype(np.float32)
 payload[0, 0] = 0.0                                   # live entry w/ value 0
 dest[0, 0] = 3
-cap = int(max(sc.capacity_bucket(int((dest == p).sum(axis=1).max()))
-              for p in range(PCNT)))
+cap = int(max((dest == p).sum(axis=1).max() for p in range(PCNT)))
 
 recv, ridx, ovf = shmap(
     lambda x, d: _s1(sc.compacted_all_to_all(x[0], d[0], cap, "part")),
     payload, dest)
 recv = np.asarray(recv).reshape(PCNT, PCNT, cap, 3)   # [dst, src, slot, D]
 ridx = np.asarray(ridx).reshape(PCNT, PCNT, cap)
-assert not bool(np.asarray(ovf).any()), "bucketed capacity must not overflow"
+assert not bool(np.asarray(ovf).any()), "the per-peer max must not overflow"
 pad = ridx < 0
 assert np.all(recv[pad] == 0), "padding slots must carry zero payload"
 # every live (src, dst) entry arrives exactly once, with its payload
@@ -97,7 +99,7 @@ print("OVF_OK")
 for density, tag in ((0.15, "sparse"), (0.0, "allinactive"), (0.9, "dense")):
     sm = (rng.random((PCNT, PCNT, V)) < density)
     vals = rng.normal(size=(PCNT, V)).astype(np.float32)
-    capm = sc.capacity_bucket(int(sm.sum(axis=2).max()))
+    capm = max(int(sm.sum(axis=2).max()), 1)
 
     def both(x, m):
         rd, md = sc.filtered_all_to_all(x[0], m[0], "part")
@@ -115,7 +117,7 @@ print("SOLO_RT_OK")
 NQ = 3
 smq = (rng.random((PCNT, PCNT, V, NQ)) < 0.2)
 valq = rng.normal(size=(PCNT, V, NQ)).astype(np.float32)
-capq = sc.capacity_bucket(int(np.any(smq, axis=3).sum(axis=2).max()))
+capq = max(int(np.any(smq, axis=3).sum(axis=2).max()), 1)
 
 
 def both_mq(x, m):
@@ -206,6 +208,70 @@ except ValueError:
 print("SHARDMAP_ENGINE_OK")
 """
 
+LADDER_CODE = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import collections
+import jax, numpy as np
+from repro.core import (make_spec, build_dist_graph, build_formats, Engine,
+                        EngineConfig)
+from repro.core import algorithms as alg
+from repro.data.graphs import rmat_graph
+
+# Dense enough that the search from the hub overflows the top rung at its
+# peak level: ladder (8, 64); solo levels ship on 8, dense, 64, 8.
+g = rmat_graph(9, 64, seed=11, weighted=True)
+spec = make_spec(g, num_partitions=8, batch_size=8)
+dg = build_dist_graph(g, spec)
+fm = build_formats(dg)
+mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("part",))
+src0 = int(np.argmax(g.out_degrees()))
+traces = collections.Counter()
+
+
+def on_trace(event, _secs, fun_name=None, **_):
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        traces[fun_name] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(on_trace)
+
+for algo, nq in (("bfs", 1), ("multi_bfs", 3)):
+    runs = {}
+    for physical in (False, True):
+        eng = Engine(dg, fm, EngineConfig(physical_sparse_exchange=physical,
+                                          num_queries=nq),
+                     mesh=mesh, axis="part")
+        name = "process_edges" if nq == 1 else "process_edges_multi"
+        pe, calls = getattr(eng, name), []
+
+        def recording(*a, pe=pe, calls=calls, **k):
+            out = pe(*a, **k)
+            calls.append((float(out[3]["net_payload_elems"]),
+                          float(out[3]["net_payload_elems_dense"])))
+            return out
+
+        setattr(eng, name, recording)
+        traces.clear()
+        if nq == 1:
+            out, st = alg.bfs(eng, src0)
+        else:
+            out, st = alg.multi_bfs(eng, [src0, 17, 3])
+        # one compiled step per engine, whatever capacity each level needs
+        assert traces["step"] == 1, (algo, physical, traces)
+        runs[physical] = out, st, calls
+    (out_off, _, _), (out_on, st_on, calls) = runs[False], runs[True]
+    np.testing.assert_array_equal(np.asarray(out_off), np.asarray(out_on),
+                                  err_msg=algo)
+    c_on = st_on.counters
+    assert c_on["exchange_compacted_iters"] >= 1, (algo, c_on)
+    assert c_on["exchange_dense_iters"] >= 1, (algo, c_on)
+    rungs = {elems for elems, dense in calls if elems < dense}
+    assert len(rungs) >= 2, (algo, calls)
+    print(algo, "ONE_STEP_OK", calls)
+print("SHARDMAP_LADDER_OK")
+"""
+
 
 def _run(code):
     env = dict(os.environ, PYTHONPATH="src")
@@ -222,4 +288,10 @@ def test_compacted_collectives_contract():
 def test_physical_exchange_engine_parity():
     r = _run(ENGINE_CODE)
     assert "SHARDMAP_ENGINE_OK" in r.stdout, (r.stdout[-1000:],
+                                              r.stderr[-3000:])
+
+
+def test_one_sharded_step_per_graph():
+    r = _run(LADDER_CODE)
+    assert "SHARDMAP_LADDER_OK" in r.stdout, (r.stdout[-1000:],
                                               r.stderr[-3000:])

@@ -141,12 +141,12 @@ def shmap(fn, *args):
        st.integers(0, 2**16))
 def prop_masked_roundtrip(v, density, seed):
     # random [P, P, V] masks incl. the all-inactive frontier; capacity
-    # bucketed from the true per-peer max -> overflow never trips and
+    # at the true per-peer max -> overflow never trips and
     # compaction + scatter-back equals filtered_all_to_all bit-for-bit.
     rng = np.random.default_rng(seed)
     sm = rng.random((PCNT, PCNT, v)) < density
     vals = rng.normal(size=(PCNT, v)).astype(np.float32)
-    cap = sc.capacity_bucket(int(sm.sum(axis=2).max()))
+    cap = max(int(sm.sum(axis=2).max()), 1)
 
     def both(x, m):
         rd, md = sc.filtered_all_to_all(x[0], m[0], "part")
