@@ -67,6 +67,7 @@ from repro.core.partition import DistGraph
 from repro.core.phases import (
     batch_touched, bitmap_model_bytes, reduce_worker_counters,
 )
+from repro.core.tracing import span
 from repro.utils import pack_bools, token_ctx, unpack_bools
 
 State = Dict[str, jnp.ndarray]      # name -> [P, V] stacked vertex arrays
@@ -546,6 +547,7 @@ class Engine:
         self._block_vals_cache: dict = {}
         self._probe_cache: dict = {}
         self._pe_cache: dict = {}
+        self._pv_cache: dict = {}
         self._warned_slot_fallback = False
         if self._distributed:
             self._shard = NamedSharding(mesh, P(axis))
@@ -857,13 +859,49 @@ class Engine:
 
         Updates vertices in ``active`` (all valid, if None); returns
         (new_state, sum of ret over active vertices, counters).  Batches with
-        no active vertex are skipped in the I/O model (paper §4.4)."""
-        g, cfg = self.graph, self.config
-        spec = g.spec
+        no active vertex are skipped in the I/O model (paper §4.4).
+
+        On LOCAL and SHARD_MAP the jitted program is cached per
+        ``fn_code_key(work_fn)`` (see :meth:`_run_pv`), so an
+        algorithm's fresh lambda each iteration compiles once; a callback
+        without a key (a closure over an array) is jitted afresh."""
         if self._ooc:
             return self._ooc_process_vertices(state, work_fn, active)
         if self._dist_ooc:
             return self._dist_process_vertices(state, work_fn, active)
+        return self._run_pv("pv", self._build_pv, work_fn, state, active)
+
+    def _run_pv(self, tag, build, work_fn, state, active):
+        """Run the jitted LOCAL / SHARD_MAP ProcessVertices program of
+        ``work_fn`` inside the span ``pv.step`` (``cached`` 1 when the
+        program came from ``_pv_cache``, 0 when ``build`` made it).
+
+        The cache key is ``fn_code_key(work_fn)`` with what else the built
+        program depends on that a call can change: the solo or panel
+        ``tag``, whether ``active`` is None and the state's tree structure
+        (SHARD_MAP builds its specs from both).  What the engine fixes when
+        built stays out (LOCAL or SHARD_MAP among it: the cache is the
+        engine's), and shapes and dtypes are ``jax.jit``'s own key.
+        A callback with no code key is built afresh every call."""
+        code = _executor.fn_code_key(work_fn)
+        key = None if code is None else (
+            tag, code, active is None, jax.tree_util.tree_structure(state))
+        fn = self._pv_cache.get(key)
+        cached = int(fn is not None)
+        if fn is None:
+            fn = build(work_fn, state, active)
+            if key is not None:
+                self._pv_cache[key] = fn
+        if self._distributed:
+            vv, gid = self._garrs["vertex_valid"], self._garrs["global_id"]
+        else:
+            vv, gid = self.graph.vertex_valid, self.global_id
+        with span("pv.step", cached=cached):
+            return fn(state, active, vv, gid)
+
+    def _build_pv(self, work_fn, state, active):
+        """The jitted solo ProcessVertices program (LOCAL or SHARD_MAP)."""
+        cfg, spec = self.config, self.graph.spec
 
         def step(state, active, vertex_valid, global_id):
             amask = vertex_valid if active is None else (active & vertex_valid)
@@ -883,8 +921,7 @@ class Engine:
             return new_state, total, counters
 
         if not self._distributed:
-            out = jax.jit(step)(state, active, g.vertex_valid, self.global_id)
-            return out
+            return jax.jit(step)
 
         mesh, axis = self.mesh, self.axis
 
@@ -899,11 +936,9 @@ class Engine:
                     None if active is None else P(axis), P(axis), P(axis))
         out_specs = (jax.tree_util.tree_map(lambda _: P(axis), state),
                      P(), {k: P() for k in COUNTER_KEYS})
-        fn = jax.jit(jax.shard_map(
+        return jax.jit(jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False))
-        return fn(state, active, self._garrs["vertex_valid"],
-                  self._garrs["global_id"])
 
     def _spill_process_vertices(self, spill, amask_rows, gid_rows, work_fn,
                                 counters):
@@ -1293,15 +1328,23 @@ class Engine:
         column (all valid, if None).  A query with an empty active column
         is physically skipped (zero vertex I/O, matching the
         ProcessEdges executors).  Returns (new_state, totals [Q],
-        counters)."""
-        g, cfg = self.graph, self.config
-        nq = cfg.num_queries
-        spec = g.spec
+        counters).
+
+        On LOCAL and SHARD_MAP the jitted program is cached per callback
+        code key, as in :meth:`process_vertices`."""
         self._check_mq_state(state, active)
         if self._ooc:
             return self._mq_ooc_process_vertices(state, work_fn, active)
         if self._dist_ooc:
             return self._mq_dist_process_vertices(state, work_fn, active)
+        return self._run_pv("pv_mq", self._build_pv_multi, work_fn, state,
+                            active)
+
+    def _build_pv_multi(self, work_fn, state, active):
+        """The jitted Q-panel ProcessVertices program (LOCAL or
+        SHARD_MAP)."""
+        cfg, spec = self.config, self.graph.spec
+        nq = cfg.num_queries
 
         def step_one(state_j, amask_j, global_id, *, psum):
             updates, ret = work_fn(state_j, global_id)
@@ -1345,8 +1388,7 @@ class Engine:
             return new_state, jnp.stack(totals), counters
 
         if not self._distributed:
-            return jax.jit(step)(state, active, g.vertex_valid,
-                                 self.global_id)
+            return jax.jit(step)
 
         mesh, axis = self.mesh, self.axis
 
@@ -1361,11 +1403,9 @@ class Engine:
                     None if active is None else P(axis), P(axis), P(axis))
         out_specs = ({k: P(axis) for k in state}, P(),
                      {k: P() for k in COUNTER_KEYS})
-        fn = jax.jit(jax.shard_map(
+        return jax.jit(jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False))
-        return fn(state, active, self._garrs["vertex_valid"],
-                  self._garrs["global_id"])
 
     def _mq_spill_process_vertices(self, spill, amask_rows, gid_rows,
                                    work_fn, base, alive, counters):

@@ -83,13 +83,21 @@ def fn_code_key(fn):
     """Hashable behavioral identity for a user callback, or None.
 
     Algorithm loops create fresh lambdas every iteration; the code object
-    (plus consts, defaults, and closure values) identifies the behavior
-    across iterations so probes and jitted executors are cached per
-    algorithm, not re-built per call."""
+    (plus consts, the global and attribute names it looks up, defaults,
+    and closure values) identifies the behavior across iterations so
+    probes and jitted executors are cached per algorithm, not re-built per
+    call.  ``co_names`` tells ``jnp.abs`` from ``jnp.exp``: the bytecode
+    of the two differs only in the name it loads.  Values carry their
+    type, since ``2 == 2.0 == True`` yet each traces another program.  A
+    closure holding an unhashable value (an array) has no key."""
+    def typed(values):
+        return tuple((type(v), v) for v in values)
+
     try:
         code = fn.__code__
-        key = (code.co_code, code.co_consts, fn.__defaults__,
-               tuple(c.cell_contents for c in (fn.__closure__ or ())))
+        key = (code.co_code, typed(code.co_consts), code.co_names,
+               typed(fn.__defaults__ or ()),
+               typed(c.cell_contents for c in (fn.__closure__ or ())))
         hash(key)
         return key
     except Exception:

@@ -163,6 +163,22 @@ def test_local_step_carries_the_phase_scopes(graph):
     assert found == set(SCOPES)
 
 
+def test_pv_step_span_marks_cache_hits(graph, tmp_path):
+    # LOCAL ProcessVertices builds its program on the first call of an
+    # algorithm and takes it from the engine's cache on every later one
+    dg, fm = graph
+    eng = Engine(dg, fm, EngineConfig())
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        algorithms.pagerank(eng, 2)
+        algorithms.pagerank(eng, 2)
+    finally:
+        jax.profiler.stop_trace()
+    cached = [args["cached"] for line in _host_spans(tmp_path)
+              for name, args in line if name == "pv.step"]
+    assert cached == [0, 1, 1, 1]
+
+
 def test_a_span_passes_exceptions_through():
     assert not jax.profiler.TraceAnnotation.is_enabled()
     with pytest.raises(KeyError, match="inside"):
